@@ -6,6 +6,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 MODULES = [
     "table1_internetwork",   # Table 1
     "fig2_hetero_memory",    # Figure 2
@@ -34,6 +36,7 @@ def main() -> None:
     p.add_argument("--only", nargs="*", default=None,
                    help="subset of benchmark modules to run")
     args = p.parse_args()
+    use_compile_cache()
     mods = args.only or MODULES
     print("name,us_per_call,derived")
     failed = []

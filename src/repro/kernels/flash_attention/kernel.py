@@ -19,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -61,12 +62,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if window is not None:
             mask &= kpos > qpos - window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new[:, None]))
+        m_prev = m_ref[...]                               # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -74,7 +75,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ik == n_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True,
@@ -111,28 +112,15 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, q.shape[1], hd), q.dtype),
         scratch_shapes=[
-            _vmem((bq,), jnp.float32),      # running max
-            _vmem((bq,), jnp.float32),      # running denominator
-            _vmem((bq, hd), jnp.float32),   # output accumulator
+            pltpu.VMEM((bq, 1), jnp.float32),    # running max
+            pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
+            pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
         ],
-        compiler_params=_tpu_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
     return out[:, :sq]
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params():
-    try:
-        from repro.kernels._compat import tpu_compiler_params
-        return tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        return None
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +128,10 @@ def _tpu_params():
 # table.  The page axis is the innermost (sequential) grid dim; each step
 # the K/V index_maps dereference `tables[b, p]` — a scalar-prefetch
 # lookup, so the DMA engine fetches exactly the pages the slot owns and
-# the dense (B, C, ...) cache view is never materialized.
+# the dense (B, C, ...) cache view is never materialized.  One grid step
+# serves all query heads of one kv head, so each page is read once per
+# group, and the q/o blocks' last two dims (group, hd) are whole array
+# dims, as Mosaic's tiling rule asks.
 # ---------------------------------------------------------------------------
 
 
@@ -162,21 +153,21 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     # either stale KV or the null page — no compute, no mask fixups.
     @pl.when(p * ps < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                  # (1, hd)
+        q = q_ref[0, 0].astype(jnp.float32)               # (group, hd)
         k = k_ref[0, 0].astype(jnp.float32)               # (ps, hd)
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (1, ps)
+            preferred_element_type=jnp.float32)           # (group, ps)
         s *= 1.0 / math.sqrt(q.shape[-1])
-        kpos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        kpos = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        pr = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new[:, None]))
+        m_prev = m_ref[...]                               # (group, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        pr = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + pr.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + pr.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pr, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -184,7 +175,7 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     @pl.when(p == n_pages_per_slot - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention_hp(q, k_pages, v_pages, tables, lengths, *,
@@ -197,40 +188,40 @@ def paged_decode_attention_hp(q, k_pages, v_pages, tables, lengths, *,
     lengths: (B,) int32 live tokens per slot — the query sits at
     position lengths[b]-1, so causality is just `kpos < length`.
     Returns (B, H, hd)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     bsz, h, hd = q.shape
     hkv, _, ps, _ = k_pages.shape
     npp = tables.shape[1]
     group = h // hkv
+    # heads [g*group, (g+1)*group) share kv head g
+    qg = q.reshape(bsz, hkv, group, hd)
 
     kernel = functools.partial(_paged_decode_kernel, ps=ps,
                                n_pages_per_slot=npp)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bsz, h, npp),
+        grid=(bsz, hkv, npp),
         in_specs=[
-            pl.BlockSpec((1, 1, hd),
-                         lambda b, i, p, tbl, ln: (b, i, 0)),
+            pl.BlockSpec((1, 1, group, hd),
+                         lambda b, g, p, tbl, ln: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, ps, hd),
-                         lambda b, i, p, tbl, ln, g=group:
-                         (i // g, tbl[b, p], 0, 0)),
+                         lambda b, g, p, tbl, ln: (g, tbl[b, p], 0, 0)),
             pl.BlockSpec((1, 1, ps, hd),
-                         lambda b, i, p, tbl, ln, g=group:
-                         (i // g, tbl[b, p], 0, 0)),
+                         lambda b, g, p, tbl, ln: (g, tbl[b, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda b, i, p, tbl, ln: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, 1, group, hd),
+                               lambda b, g, p, tbl, ln: (b, g, 0, 0)),
         scratch_shapes=[
-            _vmem((1,), jnp.float32),       # running max
-            _vmem((1,), jnp.float32),       # running denominator
-            _vmem((1, hd), jnp.float32),    # output accumulator
+            pltpu.VMEM((group, 1), jnp.float32),    # running max
+            pltpu.VMEM((group, 1), jnp.float32),    # running denominator
+            pltpu.VMEM((group, hd), jnp.float32),   # output accumulator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, h, hd), q.dtype),
-        compiler_params=_tpu_params(),
+        out_shape=jax.ShapeDtypeStruct((bsz, hkv, group, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, lengths, q, k_pages, v_pages)
+    )(tables, lengths, qg, k_pages, v_pages)
+    return out.reshape(bsz, h, hd)

@@ -1,9 +1,9 @@
 """Jit'd public wrapper for the flash attention kernel.
 
 Model-layout API: q (B, Sq, H, hd), k/v (B, Sk, Hkv, hd) — reshaped to the
-kernel's (B*H, S, hd) layout.  On non-TPU backends this falls back to
-interpret mode (the kernel body runs in Python on CPU) so the SAME code
-path is exercised everywhere; on TPU it compiles via Mosaic.
+kernel's (B*H, S, hd) layout.  On the TPU it compiles via Mosaic; on
+the CPU it runs in interpret mode (the kernel body runs in Python) so the
+same code path is exercised in tests.  Other backends raise.
 """
 from __future__ import annotations
 
@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._compat import on_tpu as _on_tpu
+from repro.kernels._backend import interpret_mode
 
 from .kernel import flash_attention_bhsd, paged_decode_attention_hp
 
@@ -31,7 +31,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     # reorder q so head-major grouping matches kv: index = b*H + h where
     # heads h in [g*group, (g+1)*group) share kv head g.  transpose above
     # already yields exactly that layout.
-    it = (not _on_tpu()) if interpret is None else interpret
+    it = interpret_mode(interpret)
     of = flash_attention_bhsd(qf, kf, vf, causal=causal, window=window,
                               bq=bq, bk=bk, interpret=it)
     return of.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
@@ -54,7 +54,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     b, _, h, hd = q.shape
     kp = k_pages.transpose(2, 0, 1, 3)   # (Hkv, P, ps, hd)
     vp = v_pages.transpose(2, 0, 1, 3)
-    it = (not _on_tpu()) if interpret is None else interpret
+    it = interpret_mode(interpret)
     out = paged_decode_attention_hp(
         q[:, 0], kp, vp, tables.astype(jnp.int32),
         lengths.astype(jnp.int32), interpret=it)

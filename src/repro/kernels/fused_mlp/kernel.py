@@ -25,7 +25,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
+# Mosaic's default scoped-VMEM limit is 16 MiB; the MLP kernels ask for more
+# and size their ff block to fit it (v5e has 128 MiB of VMEM per core).
+VMEM_LIMIT_BYTES = 64 * 2**20
+
+
+def fit_ff_block(bf: int, bt: int, d: int, itemsize: int) -> int:
+    """Halve the ff block (not below 128) until the kernel's double-buffered
+    tiles (x, the gate/up/down weight tiles, the output) and its f32
+    accumulator take at most three quarters of VMEM_LIMIT_BYTES, leaving the
+    rest to Mosaic's own scratch."""
+
+    def footprint(bf):
+        return 2 * itemsize * (2 * bt * d + 3 * d * bf) + 4 * bt * d
+
+    while bf > 128 and footprint(bf) > VMEM_LIMIT_BYTES * 3 // 4:
+        bf //= 2
+    return bf
 
 
 def _accumulate(o_ref, acc_ref, h, wo_ref, jf, n_ff_blocks):
@@ -88,7 +104,7 @@ def fused_mlp_pallas(
     n, d = x.shape
     f = wi.shape[-1]
     bt = min(bt, n)
-    bf = min(bf, f)
+    bf = min(fit_ff_block(bf, bt, d, x.dtype.itemsize), f)
     pad_n = (-n) % bt
     pad_f = (-f) % bf
     if pad_n:
@@ -119,8 +135,9 @@ def fused_mlp_pallas(
         out_specs=pl.BlockSpec((bt, d), lambda it, jf: (it, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(*operands)

@@ -1,9 +1,9 @@
 """Jit'd public wrapper for the fused dense gated-MLP kernel.
 
 Model-layout API: x is (..., d) — leading dims are flattened into one
-token axis for the kernel.  On non-TPU backends this falls back to
-interpret mode (the kernel body runs in Python on CPU) so the SAME code
-path is exercised everywhere; on TPU it compiles via Mosaic.
+token axis for the kernel.  On the TPU it compiles via Mosaic; on
+the CPU it runs in interpret mode (the kernel body runs in Python) so the
+same code path is exercised in tests.  Other backends raise.
 """
 
 from __future__ import annotations
@@ -12,7 +12,7 @@ import functools
 
 import jax
 
-from repro.kernels._compat import on_tpu as _on_tpu
+from repro.kernels._backend import interpret_mode
 
 from .kernel import fused_mlp_pallas
 
@@ -32,6 +32,6 @@ def fused_mlp(
     """wg is only read when swiglu=True; pass None for plain GELU MLPs."""
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
-    it = (not _on_tpu()) if interpret is None else interpret
+    it = interpret_mode(interpret)
     out = fused_mlp_pallas(xf, wg, wi, wo, swiglu=swiglu, bt=bt, bf=bf, interpret=it)
     return out.reshape(*lead, x.shape[-1])

@@ -23,8 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
@@ -69,7 +68,7 @@ def fused_rmsnorm_pallas(
         ],
         out_specs=pl.BlockSpec((bt, d), lambda it: (it, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], d), x.dtype),
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, g)
     return out[:n]
@@ -105,7 +104,7 @@ def fused_rmsnorm_residual_pallas(
             jax.ShapeDtypeStruct((x.shape[0], d), x.dtype),
             jax.ShapeDtypeStruct((x.shape[0], d), x.dtype),
         ],
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, res, g)
     return s[:n], out[:n]

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the fused RMSNorm(+residual) kernel.
 
 Model-layout API: x (and res) are (..., d) — leading dims are flattened
-into one token axis for the kernel.  On non-TPU backends this falls back
-to interpret mode (the kernel body runs in Python on CPU) so the SAME
-code path is exercised everywhere; on TPU it compiles via Mosaic.
+into one token axis for the kernel.  On the TPU it compiles via Mosaic; on
+the CPU it runs in interpret mode (the kernel body runs in Python) so the
+same code path is exercised in tests.  Other backends raise.
 """
 
 from __future__ import annotations
@@ -12,7 +12,7 @@ import functools
 
 import jax
 
-from repro.kernels._compat import on_tpu as _on_tpu
+from repro.kernels._backend import interpret_mode
 
 from .kernel import fused_rmsnorm_pallas, fused_rmsnorm_residual_pallas
 
@@ -23,7 +23,7 @@ def fused_rmsnorm(
 ):
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
-    it = (not _on_tpu()) if interpret is None else interpret
+    it = interpret_mode(interpret)
     out = fused_rmsnorm_pallas(xf, scale, eps=eps, bt=bt, interpret=it)
     return out.reshape(*lead, x.shape[-1])
 
@@ -35,7 +35,7 @@ def fused_rmsnorm_residual(
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     rf = res.reshape(-1, res.shape[-1])
-    it = (not _on_tpu()) if interpret is None else interpret
+    it = interpret_mode(interpret)
     s, out = fused_rmsnorm_residual_pallas(
         xf, rf, scale, eps=eps, bt=bt, interpret=it
     )

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
+from repro.kernels.fused_mlp.kernel import VMEM_LIMIT_BYTES, fit_ff_block
 
 
 def _moe_mlp_kernel(x_ref, wg_ref, wi_ref, wo_ref, o_ref, acc_ref, *,
@@ -57,7 +57,7 @@ def moe_mlp_pallas(x, wg, wi, wo, *, swiglu: bool = True, bt: int = 128,
     e, c, d = x.shape
     f = wi.shape[-1]
     bt = min(bt, c)
-    bf = min(bf, f)
+    bf = min(fit_ff_block(bf, bt, d, x.dtype.itemsize), f)
     pad_c = (-c) % bt
     pad_f = (-f) % bf
     if pad_c:
@@ -80,8 +80,9 @@ def moe_mlp_pallas(x, wg, wi, wo, *, swiglu: bool = True, bt: int = 128,
         out_specs=pl.BlockSpec((1, bt, d), lambda ie, it, jf: (ie, it, 0)),
         out_shape=jax.ShapeDtypeStruct((e, x.shape[1], d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x, wg, wi, wo)
     return out[:, :c]

@@ -5,6 +5,8 @@ import functools
 
 import jax
 
+from repro.kernels._backend import interpret_mode
+
 from .kernel import moe_mlp_pallas
 
 
@@ -12,6 +14,6 @@ from .kernel import moe_mlp_pallas
                                              "interpret"))
 def moe_mlp(x, wg, wi, wo, *, swiglu: bool = True, bt: int = 128,
             bf: int = 512, interpret: bool | None = None):
-    it = (jax.default_backend() != "tpu") if interpret is None else interpret
+    it = interpret_mode(interpret)
     return moe_mlp_pallas(x, wg, wi, wo, swiglu=swiglu, bt=bt, bf=bf,
                           interpret=it)

@@ -1,9 +1,11 @@
-"""Jit'd wrapper for the RG-LRU scan kernel with CPU interpret fallback."""
+"""Jit'd wrapper for the RG-LRU scan kernel (interpret mode on the CPU)."""
 from __future__ import annotations
 
 import functools
 
 import jax
+
+from repro.kernels._backend import interpret_mode
 
 from .kernel import rglru_scan_pallas
 
@@ -11,5 +13,5 @@ from .kernel import rglru_scan_pallas
 @functools.partial(jax.jit, static_argnames=("bs", "bw", "interpret"))
 def rglru_scan(a, b, h0, *, bs: int = 256, bw: int = 128,
                interpret: bool | None = None):
-    it = (jax.default_backend() != "tpu") if interpret is None else interpret
+    it = interpret_mode(interpret)
     return rglru_scan_pallas(a, b, h0, bs=bs, bw=bw, interpret=it)

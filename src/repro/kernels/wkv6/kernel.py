@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, s_ref,
                 *, chunk: int):
@@ -39,32 +37,37 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, s_ref,
     u = u_ref[0].astype(jnp.float32)          # (1, D) bonus
     state = s_ref[...]                        # (D, Dv)
 
-    L = jnp.cumsum(lw, axis=0)                # inclusive
+    c = chunk
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # inclusive prefix sum over time as a lower-triangular matmul
+    # (Mosaic has no cumsum)
+    L = jax.lax.dot_general(jnp.where(row >= col, 1.0, 0.0), lw,
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
     Lp = L - lw                               # exclusive
     # inter-chunk
     o = jax.lax.dot_general(r * jnp.exp(Lp), state,
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # intra-chunk: A[t,s] = sum_i r[t,i] k[s,i] exp(Lp[t,i]-L[s,i]), s<t
-    c = chunk
     P = jnp.exp(jnp.clip(Lp[:, None, :] - L[None, :, :], -60.0, 0.0))
-    tmask = (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-             > jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
-    A = jnp.einsum("ti,si,tsi->ts", r, k, P,
-                   preferred_element_type=jnp.float32)
+    tmask = row > col
+    A = jnp.sum(r[:, None, :] * k[None, :, :] * P, axis=-1)
     A = jnp.where(tmask, A, 0.0)
     diag = jnp.sum(r * u * k, axis=1)         # (C,)
-    A = A + jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-        == jax.lax.broadcasted_iota(jnp.int32, (c, c), 1),
-        diag[:, None], 0.0)
+    A = A + jnp.where(row == col, diag[:, None], 0.0)
     o = o + jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     o_ref[0, :, :] = o.astype(o_ref.dtype)
     # carry state
-    decay_all = jnp.exp(L[-1])                # (D,)
-    decay_tail = jnp.exp(jnp.clip(L[-1][None, :] - L, -60.0, 0.0))
-    s_new = state * decay_all[:, None] + jax.lax.dot_general(
+    l_end = L[c - 1:c]                        # (1, D)
+    # the chunk's total log decay as a (D, 1) column: lw^T @ ones
+    decay_all = jnp.exp(jax.lax.dot_general(
+        lw, jnp.ones((c, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))
+    decay_tail = jnp.exp(jnp.clip(l_end - L, -60.0, 0.0))
+    s_new = state * decay_all + jax.lax.dot_general(
         (k * decay_tail), v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     s_ref[...] = s_new
@@ -99,7 +102,7 @@ def wkv6_pallas(r, k, v, logw, u, s0, *, chunk: int = 64,
         out_specs=pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, r.shape[1], dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((d, dv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u, s0)

@@ -1,9 +1,11 @@
-"""Jit'd wrapper for the WKV6 kernel with CPU interpret fallback."""
+"""Jit'd wrapper for the WKV6 kernel (interpret mode on the CPU)."""
 from __future__ import annotations
 
 import functools
 
 import jax
+
+from repro.kernels._backend import interpret_mode
 
 from .kernel import wkv6_pallas
 
@@ -11,5 +13,5 @@ from .kernel import wkv6_pallas
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(r, k, v, logw, u, s0, *, chunk: int = 64,
          interpret: bool | None = None):
-    it = (jax.default_backend() != "tpu") if interpret is None else interpret
+    it = interpret_mode(interpret)
     return wkv6_pallas(r, k, v, logw, u, s0, chunk=chunk, interpret=it)

@@ -24,8 +24,11 @@ its "model" (TP) extent inside every replica while the replicas are laid
 out along the mesh "data" axis (`parallel.sharding.replica_meshes`), so
 `--policy X --replicas N` is the paper's fleet story — N copies of one
 composed BASIC behind a router, each with its own paged KV pool.
-`--rate R` drives the cluster open-loop at R req/s (Poisson, seeded)
-instead of the closed-loop burst.
+Without a policy mesh, `--replicas N` with N <= the device count puts
+one replica on each of the first N devices (`replica_mesh`); with one
+device, or more replicas than devices, the replicas share the default
+device.  `--rate R` drives the cluster open-loop at R req/s (Poisson,
+seeded) instead of the closed-loop burst.
 
 Resilience flags: `--deadline-ms D` stamps a D-millisecond SLO deadline
 on every generated request (default: the `MOZART_DEADLINE_DEFAULT_MS`
@@ -43,10 +46,12 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from repro import configs
 from repro.core.policy import ExecutionPolicy
 from repro.launch import knobs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import api, transformer
 from repro.models.config import ModelConfig
 from repro.serving.engine import Request, ServingEngine
@@ -149,6 +154,22 @@ def apply_policy(pol: ExecutionPolicy, mcfg: ModelConfig,
     return mcfg, {**kwargs, "mesh_tp": mesh_tp}, lines
 
 
+def replica_mesh(n_replicas: int):
+    """One device per replica: a ("data", "model") = (N, 1) mesh over the
+    first N devices in `jax.devices()` order, which
+    `parallel.sharding.replica_meshes` splits so that replica i runs on
+    device i.  (`jax.make_mesh` would reorder the devices along the
+    chips' physical ring; replicas never talk to each other, so the order
+    is kept plain.)  None (replicas share the default device) when the
+    process sees one device or fewer devices than replicas."""
+    devices = jax.devices()
+    if n_replicas < 2 or len(devices) < 2 or n_replicas > len(devices):
+        return None
+    return Mesh(np.asarray(devices[:n_replicas]).reshape(n_replicas, 1),
+                ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
 def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", required=True, choices=configs.ARCH_IDS)
@@ -196,6 +217,7 @@ def main() -> None:
                         "kill/restart/stall/nan) against the cluster "
                         "while it serves")
     args = p.parse_args()
+    use_compile_cache()
 
     mcfg = configs.get_smoke_config(args.arch) if args.smoke \
         else configs.get_config(args.arch)
@@ -286,7 +308,7 @@ def main() -> None:
     if n_replicas > 1:
         from repro.serving.cluster import LoadGenerator, ServingCluster
         from repro.serving.resilience import ChaosSchedule
-        mesh = eng_kwargs.pop("mesh", None)
+        mesh = eng_kwargs.pop("mesh", None) or replica_mesh(n_replicas)
         deadline_ms = args.deadline_ms if args.deadline_ms is not None \
             else float(knobs.get_int("MOZART_DEADLINE_DEFAULT_MS"))
         deadline_bands = (((deadline_ms / 1e3, deadline_ms / 1e3),)

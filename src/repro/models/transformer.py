@@ -151,20 +151,11 @@ def segment_params(seg: Params) -> Params:
 # ---------------------------------------------------------------------------
 
 def _mesh_axis_names() -> tuple:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        return tuple(m.axis_names) if m is not None else ()
-    except Exception:
-        return ()
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def _mesh_axis_size(name: str) -> int:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        return int(m.shape[name]) if m is not None and \
-            name in m.axis_names else 1
-    except Exception:
-        return 1
+    return int(jax.sharding.get_abstract_mesh().shape.get(name, 1))
 
 
 def _wsc(x, *spec):
@@ -261,7 +252,6 @@ def moe_block_shard_map(cfg: ModelConfig, p: Params, x):
     tokens x d x top_k x cf / devices), local expert GEMMs run, and the
     inverse all_to_all returns the outputs.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as _P
 
     mesh = jax.sharding.get_abstract_mesh()
@@ -321,13 +311,13 @@ def moe_block_shard_map(cfg: ModelConfig, p: Params, x):
 
     xf = x.reshape(n, d)
     wg = p.get("experts_gate", p["experts_in"])
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(_P(all_axes, None), _P(None, None),
                   _P(ep_axes, None, None), _P(ep_axes, None, None),
                   _P(ep_axes, None, None)),
         out_specs=_P(all_axes, None),
-        check_rep=False)
+        check_vma=False)
     y = fn(xf, p["router"], p["experts_in"], wg, p["experts_out"])
     y = y.reshape(bsz, s, d)
     # re-pin a clean batch-sharded layout (the reshape of a fully

@@ -15,7 +15,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = Any
@@ -78,7 +77,7 @@ def pipeline_apply(layer_fn: Callable, stage_params: Params, x,
         return out
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(staged, mesh=mesh,
-                   in_specs=(pspec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(staged, mesh=mesh,
+                       in_specs=(pspec, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x)

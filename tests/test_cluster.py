@@ -289,6 +289,77 @@ def test_replica_meshes_split_data_axis():
     assert all(m.axis_names == ("pod", "data", "model") for m in subs)
 
 
+REPLICA_MESH_PROG = r"""
+import jax
+import numpy as np
+from repro.launch.serve import replica_mesh
+from repro.models import api
+from repro.models.config import ModelConfig
+from repro.parallel.sharding import replica_meshes
+from repro.serving.cluster import ServingCluster
+from repro.serving.engine import Request
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+assert replica_mesh(1) is None and replica_mesh(5) is None
+for n in (2, 3, 4):
+    subs = replica_meshes(replica_mesh(n), n)
+    assert [m.devices.size for m in subs] == [1] * n
+    assert [m.devices.flat[0] for m in subs] == devs[:n]
+
+cfg = ModelConfig(name="t", n_layers=1, d_model=32, n_heads=2, kv_heads=1,
+                  head_dim=16, d_ff=64, vocab=61, dtype="float32",
+                  param_dtype="float32")
+params = api.init_params(cfg, jax.random.PRNGKey(0))
+streams = []
+for mesh in (replica_mesh(4), None):
+    cl = ServingCluster(cfg, params, n_replicas=4, router="round_robin",
+                        mesh=mesh, max_batch=2, max_len=32)
+    for i, eng in enumerate(cl.replicas):
+        want = devs[i] if mesh is not None else devs[0]
+        for leaf in jax.tree.leaves((eng.params, eng.pool.segments)):
+            assert leaf.devices() == {want}, (i, leaf.devices())
+    rng = np.random.default_rng(3)
+    reqs = [Request(rid=i, prompt=rng.integers(0, 61, size=int(rng.integers(3, 9)))
+                    .astype(np.int32), max_new_tokens=4) for i in range(8)]
+    for r in reqs:
+        cl.submit(r)
+    cl.run()
+    assert all(r.finish_reason == "max_new_tokens" for r in reqs)
+    streams.append([r.out_tokens for r in reqs])
+assert streams[0] == streams[1]
+print("REPLICA_MESH_OK")
+"""
+
+
+def test_serve_replica_mesh_one_device_per_replica():
+    """`serve --replicas N` without a policy mesh puts replica i's params
+    and page pool on device i (4 virtual CPU devices, in a subprocess:
+    the device count is fixed when JAX starts), and the token streams
+    equal those of the same cluster with every replica on device 0."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(
+        os.environ,
+        PYTHONPATH=os.path.join(root, "src"),
+        JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", REPLICA_MESH_PROG],
+        capture_output=True,
+        text=True,
+        timeout=600,
+        env=env,
+        cwd=root,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "REPLICA_MESH_OK" in out.stdout
+
+
 # -- int8 KV quant ------------------------------------------------------------
 
 

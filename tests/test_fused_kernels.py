@@ -130,3 +130,67 @@ def test_config_validates_impls():
         BASE.replace(mlp_impl="bogus").validate()
     with pytest.raises(AssertionError):
         BASE.replace(norm_impl="bogus").validate()
+
+
+# -- backend selection: interpret only on the CPU -----------------------------
+
+
+def _wrapper_calls():
+    """One call per Pallas ops wrapper, at shapes no other test traces (the
+    jit cache would otherwise skip the backend check)."""
+    from repro.kernels.flash_attention.ops import flash_attention, paged_decode_attention
+    from repro.kernels.moe_mlp.ops import moe_mlp
+    from repro.kernels.rglru_scan.ops import rglru_scan
+    from repro.kernels.wkv6.ops import wkv6
+
+    z = jnp.zeros
+    i32 = jnp.int32
+    return {
+        "flash_attention": lambda: flash_attention(
+            z((1, 5, 3, 8)), z((1, 5, 3, 8)), z((1, 5, 3, 8))
+        ),
+        "paged_decode_attention": lambda: paged_decode_attention(
+            z((1, 1, 3, 8)), z((3, 2, 3, 8)), z((3, 2, 3, 8)), z((1, 2), i32), z((1,), i32)
+        ),
+        "fused_mlp": lambda: fused_mlp(z((5, 24)), z((24, 40)), z((24, 40)), z((40, 24))),
+        "fused_rmsnorm": lambda: fused_rmsnorm(z((5, 24)), z((24,))),
+        "fused_rmsnorm_residual": lambda: fused_rmsnorm_residual(z((5, 24)), z((5, 24)), z((24,))),
+        "moe_mlp": lambda: moe_mlp(z((3, 5, 24)), z((3, 24, 40)), z((3, 24, 40)), z((3, 40, 24))),
+        "rglru_scan": lambda: rglru_scan(z((1, 5, 24)), z((1, 5, 24)), z((1, 24))),
+        "wkv6": lambda: wkv6(
+            z((3, 5, 8)), z((3, 5, 8)), z((3, 5, 8)), z((3, 5, 8)), z((3, 1, 8)), z((3, 8, 8))
+        ),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_wrapper_calls()))
+def test_ops_wrapper_refuses_non_tpu_backend(monkeypatch, name):
+    """On a backend that is neither the CPU nor the TPU a kernel must not
+    quietly run in interpret mode: the wrapper raises instead."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="compile only for the TPU"):
+        _wrapper_calls()[name]()
+
+
+def test_interpret_mode_by_backend(monkeypatch):
+    from repro.kernels._backend import interpret_mode
+
+    assert interpret_mode() is True  # tests run under JAX_PLATFORMS=cpu
+    assert interpret_mode(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    assert interpret_mode(True) is True
+
+
+def test_ff_block_fits_vmem_at_large_widths():
+    """The MLP kernels keep SmolLM's ff block and halve it where the
+    double-buffered weight tiles would overflow the VMEM limit."""
+    from repro.kernels.fused_mlp.kernel import VMEM_LIMIT_BYTES, fit_ff_block
+
+    assert fit_ff_block(512, 128, 576, 2) == 512  # SmolLM-135M
+    assert fit_ff_block(512, 128, 4096, 2) == 512  # Mixtral experts
+    assert fit_ff_block(512, 128, 7168, 2) == 256  # DeepSeek-V3 experts
+    assert fit_ff_block(512, 128, 7168, 4) == 128
+    for d in (576, 4096, 7168):
+        bf = fit_ff_block(512, 128, d, 2)
+        assert 2 * 2 * (2 * 128 * d + 3 * d * bf) + 4 * 128 * d <= VMEM_LIMIT_BYTES
