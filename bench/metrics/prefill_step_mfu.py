@@ -1,0 +1,16 @@
+"""Whole prefill against the chip's peak (%): the least time of the
+needed work (real prompt tokens through every layer, causal attention,
+one row of logits) over the device time inside the `bench.prefill`
+spans."""
+
+from bench import flops
+
+
+def read(rec):
+    spans = rec.spans("prefill")
+    dev = sum(rec.span_device_ns(s) for s in spans) * 1e-9
+    if not dev:
+        return None
+    need = sum(flops.least_time(*flops.prefill_cost(rec.model, int(s[3]["tokens"])), rec.peak)
+               for s in spans)
+    return 100.0 * need / dev
