@@ -75,7 +75,8 @@ def substrate() -> None:
             max_new_tokens=12))
     t0 = time.time()
     eng.run()
-    occ = float(np.mean(eng.stats["slot_occupancy"]))
+    occ = eng.stats["live_slot_steps"] / (
+        eng.stats["decode_steps"] * eng.max_batch)
     print(f"continuous batching: {eng.stats['tokens_out']} tokens in "
           f"{time.time() - t0:.1f}s, occupancy {occ:.2f}")
 

@@ -38,10 +38,18 @@ knob; 0 = none) — the engines shed requests that cannot meet it
 `serving.resilience.ChaosSchedule.generate`) against the cluster while
 it serves, and the summary reports the shed / poisoned / quarantined /
 unrouted counts next to goodput (deadline-met tokens).
+
+`--profile DIR` turns on the engine's spans (`serving.spans`) and
+records a JAX profiler trace of the serving loop under DIR: the
+`serve.*` spans of every engine step sit on the same timeline as the
+device's operations and the named programs (`jit_paged_decode`,
+`jit_paged_prefill`, ...).  Open it in TensorBoard's profile plugin or
+Perfetto.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -54,6 +62,7 @@ from repro.launch import knobs
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import api, transformer
 from repro.models.config import ModelConfig
+from repro.serving import spans
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.specdec import spec_decode_greedy
 
@@ -216,8 +225,15 @@ def main() -> None:
                    help="replay a seeded fault script (MOZART_CHAOS_SEED: "
                         "kill/restart/stall/nan) against the cluster "
                         "while it serves")
+    p.add_argument("--profile", default=None, metavar="DIR",
+                   help="turn the engine's serve.* spans on and write a "
+                        "JAX profiler trace of the serving loop under DIR")
     args = p.parse_args()
     use_compile_cache()
+
+    def serving_loop():
+        return spans.profile(args.profile) if args.profile \
+            else contextlib.nullcontext()
 
     mcfg = configs.get_smoke_config(args.arch) if args.smoke \
         else configs.get_config(args.arch)
@@ -294,7 +310,8 @@ def main() -> None:
                                            size=plen).astype(np.int32),
                 max_new_tokens=args.max_new))
         t0 = time.time()
-        eng.run()
+        with serving_loop():
+            eng.run()
         dt = time.time() - t0
         st = eng.spec_stats
         print(f"[serve] specdec-live: {eng.stats['tokens_out']} tokens in "
@@ -328,7 +345,8 @@ def main() -> None:
             print(f"[serve] chaos script: "
                   f"{[(e.step, e.kind, e.replica) for e in chaos.events]}")
         t0 = time.time()
-        summary = cl.drive(lg.schedule(), chaos=chaos)
+        with serving_loop():
+            summary = cl.drive(lg.schedule(), chaos=chaos)
         dt = time.time() - t0
         agg = summary["aggregate"]
         print(f"[serve] cluster x{n_replicas} router={cl.router.policy} "
@@ -359,10 +377,11 @@ def main() -> None:
                                        size=plen).astype(np.int32),
             max_new_tokens=args.max_new))
     t0 = time.time()
-    eng.run()
+    with serving_loop():
+        eng.run()
     dt = time.time() - t0
-    occ = float(np.mean(eng.stats["slot_occupancy"])) \
-        if eng.stats["slot_occupancy"] else 0.0
+    occ = eng.stats["live_slot_steps"] / max(
+        eng.stats["decode_steps"] * eng.max_batch, 1)
     print(f"[serve] {eng.stats['tokens_out']} tokens, "
           f"{eng.stats['decode_steps']} steps, "
           f"{eng.stats['prefills']} prefills in {dt:.2f}s "

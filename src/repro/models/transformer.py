@@ -699,7 +699,8 @@ def _decode_attn(cfg: ModelConfig, p: Params, x, seg_cache, index):
         new_entry = jnp.concatenate([ckv, k_rope[:, :, 0, :]], -1)  # (B,1,D)
         clen = seg_cache["latent"].shape[1]
         slot = _ring_slot(cfg, index, clen)
-        cache = _scatter_slot(seg_cache["latent"], new_entry, slot)
+        with jax.named_scope("kv_write"):
+            cache = _scatter_slot(seg_cache["latent"], new_entry, slot)
         # (B, C, kvr+rd)
         lat, lat_rope = cache[..., :kvr], cache[..., kvr:]
         # absorbed attention: q_nope^T W_uk c_kv
@@ -733,8 +734,9 @@ def _decode_attn(cfg: ModelConfig, p: Params, x, seg_cache, index):
     K, V = seg_cache["k"], seg_cache["v"]           # (B, C, kvh, hd)
     clen = K.shape[1]
     slot = _ring_slot(cfg, index, clen)
-    K = _scatter_slot(K, k, slot)
-    V = _scatter_slot(V, v, slot)
+    with jax.named_scope("kv_write"):
+        K = _scatter_slot(K, k, slot)
+        V = _scatter_slot(V, v, slot)
     n_rep = cfg.n_heads // cfg.kv_heads
     kpos = _cache_positions(cfg, index, clen)       # (B, C)
     mask = (kpos >= 0) & (kpos <= index[:, None])

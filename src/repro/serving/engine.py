@@ -58,8 +58,8 @@ params and KV cache (dense slabs or page pools) are placed with
 `parallel.sharding`'s rules and the jitted prefill/decode run sharded
 over the mesh.  `mesh=None` is the single-device no-op path.
 
-Requests carry wall-clock marks (`t_submit`/`t_first`/`t_done`) so the
-serving benchmark can report TTFT/TPOT percentiles, and a
+Requests carry wall-clock marks (`t_submit`/`t_admit`/`t_first`/
+`t_done`) from which TTFT/TPOT percentiles are computed, and a
 `finish_reason` ("eos", "max_new_tokens", "length" at the cache
 boundary, "rejected" for prompts that cannot fit, "capacity" when a lone
 request exhausts the page pool, "shed" for deadline/overload shedding,
@@ -82,10 +82,20 @@ Every decode's logits pass a cheap jitted all-finite guard
 `health["nan_detected"]` and the step emits nothing, so corrupted KV
 can never leak garbage tokens — the cluster watchdog quarantines the
 replica and the requeue path recovers its requests token-exactly.
+
+SPANS AND COUNTERS.  With `serving.spans` on, `step()` writes
+`serve.step` and, nested inside it, `serve.admit`, one `serve.prefill`
+per admitted request, `serve.grow`, `serve.decode`, `serve.guard` and
+`serve.sample` into the profiler's trace.  `stats["host_syncs"]` counts
+every read of a device value by the host (each sampled token, each NaN
+guard); `stats["live_slot_steps"]` sums the live slots over decode
+steps, so mean occupancy is `live_slot_steps / (decode_steps *
+max_batch)`.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any
 
@@ -96,6 +106,7 @@ from repro.launch import knobs
 from repro.models.config import ModelConfig
 from . import paged as paged_kv
 from . import resilience
+from . import spans
 from . import state as state_mod
 from .sampling import sample
 
@@ -128,6 +139,7 @@ class Request:
     finish_reason: str | None = None
     # wall-clock marks for TTFT/TPOT accounting (monotonic seconds)
     t_submit: float | None = None
+    t_admit: float | None = None  # first prefill's start; kept on resume
     t_first: float | None = None
     t_done: float | None = None
     admit_seq: int = -1           # engine admission order (preemption picks max)
@@ -249,9 +261,9 @@ class ServingEngine:
             paged_kv.paged_decode_fn(mcfg, self.kv_quant) if self.paged \
             else None
         self.stats = {"decode_steps": 0, "prefills": 0,
-                      "tokens_out": 0, "slot_occupancy": [],
+                      "tokens_out": 0, "live_slot_steps": 0,
                       "preemptions": 0, "rejected": 0,
-                      "shed": 0, "nan_steps": 0}
+                      "shed": 0, "nan_steps": 0, "host_syncs": 0}
 
     @property
     def cache(self):
@@ -350,59 +362,74 @@ class ServingEngine:
         """Prefill queued requests into free slots (continuous batching).
         Prompts that could never decode a single token inside the cache
         are rejected up front instead of silently overrunning the slot."""
-        for b in range(self.max_batch):
-            if self.slots[b] is not None or not self.queue:
-                continue
-            qi = self._next_admission()
-            if qi is None:
-                break
-            req = self.queue[qi]
-            resumed = bool(req.out_tokens)
-            if resumed:
-                # re-prefill everything but the newest token (whose KV
-                # would have been written by its decode step)
-                seq = np.concatenate([
-                    np.asarray(req.prompt, np.int32),
-                    np.asarray(req.out_tokens[:-1], np.int32)])
-            else:
-                seq = np.asarray(req.prompt, np.int32)
-            plen = len(seq)
-            if plen < 1 or plen + self._headroom > self.capacity:
-                self.queue.pop(qi)
-                req.done = True
-                req.finish_reason = "rejected"
-                req.t_done = time.monotonic()
-                self.stats["rejected"] += 1
-                continue
-            if self.paged:
+        admitted = 0
+        with spans.span("admit", queued=len(self.queue)) as sp:
+            for b in range(self.max_batch):
+                if self.slots[b] is not None or not self.queue:
+                    continue
+                qi = self._next_admission()
+                if qi is None:
+                    break
+                req = self.queue[qi]
+                resumed = bool(req.out_tokens)
+                if resumed:
+                    # re-prefill everything but the newest token (whose KV
+                    # would have been written by its decode step)
+                    seq = np.concatenate([
+                        np.asarray(req.prompt, np.int32),
+                        np.asarray(req.out_tokens[:-1], np.int32)])
+                else:
+                    seq = np.asarray(req.prompt, np.int32)
+                plen = len(seq)
+                if plen < 1 or plen + self._headroom > self.capacity:
+                    self.queue.pop(qi)
+                    req.done = True
+                    req.finish_reason = "rejected"
+                    req.t_done = time.monotonic()
+                    self.stats["rejected"] += 1
+                    continue
                 # +1: the next decode writes KV at position plen
-                if not self.pool.ensure(b, plen + 1):
-                    break       # pool dry — wait for decode-side frees
-                last = self.state.prefill(self._prefill, self.params,
-                                          b, seq)
-            else:
-                last = self._dense_prefill(b, seq, req)
-            self.queue.pop(qi)
-            self.slots[b] = req
-            req.admit_seq = self._admit_counter
-            self._admit_counter += 1
-            self.stats["prefills"] += 1
-            if resumed:
-                self.next_token[b, 0] = req.out_tokens[-1]
-                continue
-            self.key, k = jax.random.split(self.key)
-            tok = int(sample(last[0, -1:], k,
-                             temperature=req.temperature)[0])
-            req.out_tokens.append(tok)
-            if req.t_first is None:
-                req.t_first = time.monotonic()
-            self.next_token[b, 0] = tok
-            self.stats["tokens_out"] += 1
-            if len(req.out_tokens) >= req.max_new_tokens or \
-                    tok == self.eos_id:
-                # budget spent at admission — never decode past max_new
-                self._finish(b, "eos" if tok == self.eos_id
-                             else "max_new_tokens")
+                if self.paged and not self.pool.ensure(b, plen + 1):
+                    break           # pool dry — wait for decode-side frees
+                if req.t_admit is None:
+                    req.t_admit = time.monotonic()
+                with spans.span("prefill", rid=req.rid, tokens=plen,
+                                bucket=functools.partial(self._bucket, plen),
+                                resumed=resumed):
+                    if self.paged:
+                        last = self.state.prefill(self._prefill, self.params,
+                                                  b, seq)
+                    else:
+                        last = self._dense_prefill(b, seq, req)
+                    self.queue.pop(qi)
+                    self.slots[b] = req
+                    req.admit_seq = self._admit_counter
+                    self._admit_counter += 1
+                    self.stats["prefills"] += 1
+                    admitted += 1
+                    if resumed:
+                        self.next_token[b, 0] = req.out_tokens[-1]
+                        continue
+                    self.key, k = jax.random.split(self.key)
+                    tok = int(sample(last[0, -1:], k,
+                                     temperature=req.temperature)[0])
+                    self.stats["host_syncs"] += 1
+                    req.out_tokens.append(tok)
+                    if req.t_first is None:
+                        req.t_first = time.monotonic()
+                    self.next_token[b, 0] = tok
+                    self.stats["tokens_out"] += 1
+                    if len(req.out_tokens) >= req.max_new_tokens or \
+                            tok == self.eos_id:
+                        # budget spent at admission — never decode past max_new
+                        self._finish(b, "eos" if tok == self.eos_id
+                                     else "max_new_tokens")
+            sp.set_metadata(admitted=admitted)
+
+    def _bucket(self, plen: int) -> int:
+        """The length a `plen`-token prefill is padded to."""
+        return paged_kv.bucket_for(plen, self.buckets) if self.buckets \
+            else plen
 
     def _dense_prefill(self, b: int, seq: np.ndarray, req: Request):
         """Dense-state prefill hook (SpecDecodeEngine also prefills the
@@ -431,24 +458,26 @@ class ServingEngine:
             # (the requeue path recovers every request token-exactly)
             return 0
         t_step = time.monotonic()
-        self._admit()
-        live = [b for b, r in enumerate(self.slots) if r is not None]
-        # cache-boundary: a slot whose next KV write(s) would land at or
-        # past capacity finishes NOW instead of silently overrunning it
-        for b in list(live):
-            if self._slot_pos(b) + self._headroom > self.capacity:
-                self._finish(b, "length")
-                live.remove(b)
-        if self.paged:
-            live = self._grow_pages(live)
-        if not live:
-            return 0
-        active = self._select_active(live)
-        if not self._advance(active):
-            return 0            # non-finite logits: emitted nothing
+        with spans.span("step") as sp:
+            self._admit()
+            live = [b for b, r in enumerate(self.slots) if r is not None]
+            # cache-boundary: a slot whose next KV write(s) would land at
+            # or past capacity finishes NOW instead of overrunning it
+            for b in list(live):
+                if self._slot_pos(b) + self._headroom > self.capacity:
+                    self._finish(b, "length")
+                    live.remove(b)
+            if self.paged:
+                live = self._grow_pages(live)
+            sp.set_metadata(live=len(live))
+            if not live:
+                return 0
+            active = self._select_active(live)
+            sp.set_metadata(active=len(active))
+            if not self._advance(active):
+                return 0        # non-finite logits: emitted nothing
         self.stats["decode_steps"] += 1
-        self.stats["slot_occupancy"].append(
-            len(live) / self.max_batch)
+        self.stats["live_slot_steps"] += len(live)
         dt = time.monotonic() - t_step
         # EWMA per-step pace: the deadline-feasibility estimate _admit
         # sheds against (first measurement seeds it directly)
@@ -456,50 +485,67 @@ class ServingEngine:
             else 0.8 * self._est_step_s + 0.2 * dt
         return len(active)
 
+    def _live_positions(self, active: list[int]) -> int:
+        """KV positions one decode of `active` reads: each slot's cache
+        length plus the position it writes."""
+        return sum(self._slot_pos(b) + 1 for b in active)
+
     def _advance(self, active: list[int]) -> bool:
         """Decode the active slots one step, guard, sample, finish.
         Returns False when the NaN guard swallowed the step.  Subclasses
         (spec-decode) replace this with multi-token propose/verify."""
         fn = self._paged_decode if self.paged else self._decode
-        logits, lane = self.state.decode(fn, self.params,
-                                         self.next_token, active)
-        if self.guard_nan and not resilience.logits_finite(logits):
-            # corrupted KV / sick kernel: emit NOTHING from non-finite
-            # logits (garbage tokens would poison the requests' streams
-            # beyond token-exact recovery); flag for the watchdog
-            self.health["nan_detected"] = True
-            self.stats["nan_steps"] += 1
-            return False
-        for b in active:
-            req = self.slots[b]
-            self.key, k = jax.random.split(self.key)
-            tok = int(sample(logits[lane[b], -1:], k,
-                             temperature=req.temperature)[0])
-            req.out_tokens.append(tok)
-            self.next_token[b, 0] = tok
-            self.stats["tokens_out"] += 1
-            if len(req.out_tokens) >= req.max_new_tokens or \
-                    tok == self.eos_id:
-                self._finish(b, "eos" if tok == self.eos_id
-                             else "max_new_tokens")
+        with spans.span("decode", active=len(active),
+                        ctx=functools.partial(self._live_positions, active)):
+            logits, lane = self.state.decode(fn, self.params,
+                                             self.next_token, active)
+        if self.guard_nan:
+            with spans.span("guard"):
+                finite = resilience.logits_finite(logits)
+            self.stats["host_syncs"] += 1
+            if not finite:
+                # corrupted KV / sick kernel: emit NOTHING from non-finite
+                # logits (garbage tokens would poison the requests'
+                # streams beyond token-exact recovery); flag for the
+                # watchdog
+                self.health["nan_detected"] = True
+                self.stats["nan_steps"] += 1
+                return False
+        with spans.span("sample", n=len(active)):
+            for b in active:
+                req = self.slots[b]
+                self.key, k = jax.random.split(self.key)
+                tok = int(sample(logits[lane[b], -1:], k,
+                                 temperature=req.temperature)[0])
+                self.stats["host_syncs"] += 1
+                req.out_tokens.append(tok)
+                self.next_token[b, 0] = tok
+                self.stats["tokens_out"] += 1
+                if len(req.out_tokens) >= req.max_new_tokens or \
+                        tok == self.eos_id:
+                    self._finish(b, "eos" if tok == self.eos_id
+                                 else "max_new_tokens")
         return True
 
     def _grow_pages(self, live: list[int]) -> list[int]:
         """Make every live slot's next KV write backed by a page,
         preempting the youngest-admitted slot under pool pressure; a lone
         slot that exhausts the pool finishes with reason "capacity"."""
-        for b in list(live):
-            while b in live and \
-                    not self.pool.ensure(b, self._slot_pos(b) + 1):
-                victims = [v for v in live if v != b]
-                if not victims:
-                    self._finish(b, "capacity")
-                    live.remove(b)
-                else:
-                    v = max(victims,
-                            key=lambda s: self.slots[s].admit_seq)
-                    self._preempt(v)
-                    live.remove(v)
+        before = self.stats["preemptions"]
+        with spans.span("grow") as sp:
+            for b in list(live):
+                while b in live and \
+                        not self.pool.ensure(b, self._slot_pos(b) + 1):
+                    victims = [v for v in live if v != b]
+                    if not victims:
+                        self._finish(b, "capacity")
+                        live.remove(b)
+                    else:
+                        v = max(victims,
+                                key=lambda s: self.slots[s].admit_seq)
+                        self._preempt(v)
+                        live.remove(v)
+            sp.set_metadata(preempted=self.stats["preemptions"] - before)
         return live
 
     def run(self, max_steps: int = 10_000) -> None:

@@ -255,26 +255,31 @@ def paged_decode_fn(mcfg: ModelConfig, quantized: bool = False):
     and the pool round-trip the device.  The quantized variant takes and
     returns the scale tree alongside the int8 pool."""
 
-    def fn(params, tokens, segments, tables_sel, index_sel):
-        dense = _gather_pages(segments, tables_sel)
+    def paged_decode(params, tokens, segments, tables_sel, index_sel):
+        with jax.named_scope("kv_gather"):
+            dense = _gather_pages(segments, tables_sel)
         logits, new = api.decode_step(
             mcfg, params, tokens, {"segments": dense, "index": index_sel}
         )
-        return logits, _scatter_pages(segments, new["segments"], tables_sel)
+        with jax.named_scope("kv_scatter"):
+            segments = _scatter_pages(segments, new["segments"], tables_sel)
+        return logits, segments
 
-    def fn_q(params, tokens, segments, scales, tables_sel, index_sel):
-        dense = _gather_pages_dequant(segments, scales, tables_sel)
+    def paged_decode_int8(params, tokens, segments, scales, tables_sel, index_sel):
+        with jax.named_scope("kv_gather"):
+            dense = _gather_pages_dequant(segments, scales, tables_sel)
         logits, new = api.decode_step(
             mcfg, params, tokens, {"segments": dense, "index": index_sel}
         )
-        segs2, scales2 = _scatter_pages_quant(
-            segments, scales, new["segments"], tables_sel, new["index"]
-        )
+        with jax.named_scope("kv_scatter"):
+            segs2, scales2 = _scatter_pages_quant(
+                segments, scales, new["segments"], tables_sel, new["index"]
+            )
         return logits, segs2, scales2
 
     if quantized:
-        return jax.jit(fn_q, donate_argnums=(2, 3))
-    return jax.jit(fn, donate_argnums=(2,))
+        return jax.jit(paged_decode_int8, donate_argnums=(2, 3))
+    return jax.jit(paged_decode, donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=32)
@@ -318,7 +323,7 @@ def paged_prefill_fn(
     def _pages(kv):  # (L, 1, bucket, ...) -> (L, npp_b, page_size, ...)
         return kv[:, 0].reshape(kv.shape[0], npp_b, page_size, *kv.shape[3:])
 
-    def fn(params, toks, plen, segments, table_row):
+    def paged_prefill(params, toks, plen, segments, table_row):
         logits, _, kvs = transformer.forward(mcfg, params, toks, collect_kv=True)
         last = jax.lax.dynamic_slice_in_dim(logits, plen - 1, 1, axis=1)
         kv_trees, page_live = _masked_kv(plen, kvs)
@@ -333,7 +338,7 @@ def paged_prefill_fn(
         ]
         return last, new_segs
 
-    def fn_q(params, toks, plen, segments, scales, table_row):
+    def paged_prefill_int8(params, toks, plen, segments, scales, table_row):
         logits, _, kvs = transformer.forward(mcfg, params, toks, collect_kv=True)
         last = jax.lax.dynamic_slice_in_dim(logits, plen - 1, 1, axis=1)
         kv_trees, page_live = _masked_kv(plen, kvs)
@@ -355,8 +360,8 @@ def paged_prefill_fn(
         return last, new_segs, new_scales
 
     if quantized:
-        return jax.jit(fn_q, donate_argnums=(3, 4))
-    return jax.jit(fn, donate_argnums=(3,))
+        return jax.jit(paged_prefill_int8, donate_argnums=(3, 4))
+    return jax.jit(paged_prefill, donate_argnums=(3,))
 
 
 def paged_supported(mcfg: ModelConfig) -> bool:

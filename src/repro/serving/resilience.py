@@ -49,9 +49,15 @@ import numpy as np
 
 from repro.launch import knobs
 
+def _all_finite(x):
+    return jnp.isfinite(x).all()
+
+
 # one executable per logits shape (decode width is fixed in steady
-# state), reused across engines via the module-level jit cache
-_ALL_FINITE = jax.jit(lambda x: jnp.isfinite(x).all())
+# state), reused across engines via the module-level jit cache; profiles
+# show it as `jit_logits_finite`
+_all_finite.__name__ = "logits_finite"
+_ALL_FINITE = jax.jit(_all_finite)
 
 
 def logits_finite(logits) -> bool:

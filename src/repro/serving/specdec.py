@@ -35,6 +35,7 @@ import numpy as np
 from repro.launch import knobs
 from repro.models import api, transformer
 from repro.models.config import ModelConfig
+from . import spans
 from . import state as state_mod
 from .engine import Request, ServingEngine
 from .state import _GATHER, _SCATTER, _lane_map
@@ -264,45 +265,54 @@ class SpecDecodeEngine(ServingEngine):
         k = self.k
         sel = active + [active[0]] * (self.decode_batch - len(active))
         sel_arr = jnp.asarray(sel, jnp.int32)
-        tok = jnp.asarray(self.next_token[sel])
-        dft_sub = _GATHER(self.draft_state.cache, sel_arr)
-        drafts, dft_sub = self._propose(self.draft_params, tok, dft_sub)
-        tgt_sub = _GATHER(self.state.cache, sel_arr)
-        window = jnp.concatenate([tok, drafts[:, :-1]], axis=1)   # (w, k)
-        choice, finite, tgt_sub = self._verify(self.params, window, tgt_sub)
-        if self.guard_nan and not bool(finite):
-            self.health["nan_detected"] = True
-            self.stats["nan_steps"] += 1
-            return False        # sub-caches dropped: nothing scattered
-        drafts_np = np.asarray(drafts)
-        choice_np = np.asarray(choice)
-        lane = _lane_map(sel)
-        consumed_by_slot: dict[int, int] = {}
-        for b in active:
-            j = lane[b]
-            req = self.slots[b]
-            n = 0
-            while n < k - 1 and drafts_np[j, n] == choice_np[j, n]:
-                n += 1
-            emitted = [int(t) for t in drafts_np[j, :n]] + \
-                [int(choice_np[j, n])]
-            self.spec_stats.iterations += 1
-            self.spec_stats.proposed += k - 1
-            self.spec_stats.accepted += n
-            self.spec_stats.bonus += 1
-            # budget / eos truncation: a cut always finishes the slot,
-            # so the dropped tail's (already written) KV is never read
-            out = emitted[:req.max_new_tokens - len(req.out_tokens)]
-            if self.eos_id in out:
-                out = out[:out.index(self.eos_id) + 1]
-            req.out_tokens.extend(out)
-            self.next_token[b, 0] = out[-1]
-            self.stats["tokens_out"] += len(out)
-            consumed_by_slot[b] = len(out)
-            if len(req.out_tokens) >= req.max_new_tokens or \
-                    out[-1] == self.eos_id:
-                self._finish(b, "eos" if out[-1] == self.eos_id
-                             else "max_new_tokens")
+        with spans.span("decode", active=len(active),
+                        ctx=functools.partial(self._live_positions, active)):
+            tok = jnp.asarray(self.next_token[sel])
+            dft_sub = _GATHER(self.draft_state.cache, sel_arr)
+            drafts, dft_sub = self._propose(self.draft_params, tok, dft_sub)
+            tgt_sub = _GATHER(self.state.cache, sel_arr)
+            window = jnp.concatenate([tok, drafts[:, :-1]], axis=1)  # (w, k)
+            choice, finite, tgt_sub = self._verify(self.params, window,
+                                                   tgt_sub)
+        if self.guard_nan:
+            with spans.span("guard"):
+                finite = bool(finite)
+            self.stats["host_syncs"] += 1
+            if not finite:
+                self.health["nan_detected"] = True
+                self.stats["nan_steps"] += 1
+                return False    # sub-caches dropped: nothing scattered
+        with spans.span("sample", n=len(active)):
+            drafts_np = np.asarray(drafts)
+            choice_np = np.asarray(choice)
+            self.stats["host_syncs"] += 2
+            lane = _lane_map(sel)
+            consumed_by_slot: dict[int, int] = {}
+            for b in active:
+                j = lane[b]
+                req = self.slots[b]
+                n = 0
+                while n < k - 1 and drafts_np[j, n] == choice_np[j, n]:
+                    n += 1
+                emitted = [int(t) for t in drafts_np[j, :n]] + \
+                    [int(choice_np[j, n])]
+                self.spec_stats.iterations += 1
+                self.spec_stats.proposed += k - 1
+                self.spec_stats.accepted += n
+                self.spec_stats.bonus += 1
+                # budget / eos truncation: a cut always finishes the slot,
+                # so the dropped tail's (already written) KV is never read
+                out = emitted[:req.max_new_tokens - len(req.out_tokens)]
+                if self.eos_id in out:
+                    out = out[:out.index(self.eos_id) + 1]
+                req.out_tokens.extend(out)
+                self.next_token[b, 0] = out[-1]
+                self.stats["tokens_out"] += len(out)
+                consumed_by_slot[b] = len(out)
+                if len(req.out_tokens) >= req.max_new_tokens or \
+                        out[-1] == self.eos_id:
+                    self._finish(b, "eos" if out[-1] == self.eos_id
+                                 else "max_new_tokens")
         consumed = jnp.asarray([consumed_by_slot[b] for b in sel],
                                jnp.int32)
         tgt_sub = {"segments": tgt_sub["segments"],

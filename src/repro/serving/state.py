@@ -136,13 +136,18 @@ def _decode_fn(mcfg: ModelConfig):
     """Shared per-config jitted decode (engines with the same config —
     e.g. benchmark variants — reuse one trace cache).  Bounded: a config
     sweep evicts old executables instead of retaining them forever."""
-    return jax.jit(lambda p, t, c: api.decode_step(mcfg, p, t, c))
+    def decode(p, t, c):
+        return api.decode_step(mcfg, p, t, c)
+
+    return jax.jit(decode)
 
 
 @functools.lru_cache(maxsize=8)
 def _prefill_fn(mcfg: ModelConfig, max_len: int):
-    return jax.jit(
-        lambda p, toks: api.prefill(mcfg, p, {"tokens": toks}, max_len))
+    def prefill(p, toks):
+        return api.prefill(mcfg, p, {"tokens": toks}, max_len)
+
+    return jax.jit(prefill)
 
 
 @functools.lru_cache(maxsize=8)
