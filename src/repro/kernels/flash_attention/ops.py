@@ -1,20 +1,25 @@
-"""Jit'd public wrapper for the flash attention kernel.
+"""Jit'd public wrappers for the attention kernels.
 
-Model-layout API: q (B, Sq, H, hd), k/v (B, Sk, Hkv, hd) — reshaped to the
-kernel's (B*H, S, hd) layout.  On the TPU it compiles via Mosaic; on
-the CPU it runs in interpret mode (the kernel body runs in Python) so the
-same code path is exercised in tests.  Other backends raise.
+`flash_attention`: model layout q (B, Sq, H, hd), k/v (B, Sk, Hkv, hd),
+reshaped to the kernel's (B*H, S, hd) layout.  `paged_decode_attention`:
+the serving decode's attention, which reads K/V in place from the page
+pool through the page tables (no dense cache view, no grouped-head
+repeat).  On the TPU they compile via Mosaic; on the CPU they run in
+interpret mode (the kernel body runs in Python) so the same code path is
+exercised in tests.  Other backends raise.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels._backend import interpret_mode
 
-from .kernel import flash_attention_bhsd, paged_decode_attention_hp
+from .kernel import (flash_attention_bhsd, paged_decode_attention_hp,
+                     to_pool_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
@@ -37,25 +42,43 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return of.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
-                           interpret: bool | None = None) -> jnp.ndarray:
-    """Paged single-token decode attention (vLLM-style): attend one query
-    per sequence through a page table instead of a dense (B, C, ...)
-    cache slab.
+# positions one grid step of the paged decode kernel covers: enough for the
+# page DMAs of a block to outlast the step's fixed cost
+BLOCK_POSITIONS = 128
 
-    Model-layout API matching the serving page pools: q (B, 1, H, hd) —
-    the current token; k_pages/v_pages (P, ps, Hkv, hd) — one layer's
-    page pool from `models.api.init_paged_cache` (page 0 reserved as the
-    never-read null page); tables (B, n_pages_per_slot) int32 physical
-    page ids; lengths (B,) int32 live tokens per slot INCLUDING the
-    current token (whose k/v must already be scattered into the pages).
-    Returns (B, 1, H, hd)."""
-    b, _, h, hd = q.shape
-    kp = k_pages.transpose(2, 0, 1, 3)   # (Hkv, P, ps, hd)
-    vp = v_pages.transpose(2, 0, 1, 3)
-    it = interpret_mode(interpret)
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, layer, tables,
+                           lengths, *, interpret: bool | None = None
+                           ) -> jnp.ndarray:
+    """Single-token decode attention read straight from the page pool.
+
+    q (B, H, hd): the current token's queries; k_new/v_new (B, Hkv, hd):
+    its keys and values, which are not in the pool yet; k_pool/v_pool
+    (L, P, ps, W): every layer's pages in `to_pool_rows`' layout, W =
+    `kernel.pool_row_width(Hkv, hd)`, as `models.api.init_paged_cache`
+    lays them out (page 0 is the never-read null page); layer: int32
+    scalar, the layer to read; tables
+    (B, n_pages_per_slot) int32 physical page ids; lengths (B,) int32
+    tokens each sequence holds in the pool.  The current token sits at
+    position lengths[b] and attends to the pool's positions below it and
+    to itself.  Returns (B, H, hd)."""
+    b, h, hd = q.shape
+    hkv = k_new.shape[1]
+    group = h // hkv
+    ps, w = k_pool.shape[2:]
+    npp = tables.shape[1]
+    ppb = max(1, min(BLOCK_POSITIONS // ps, npp))
+    tables = jnp.pad(tables, ((0, 0), (0, -npp % ppb)))
+
+    # query head j keeps its vector in kv head j // group's columns
+    own = jnp.arange(h)[:, None] // group == jnp.arange(hkv)[None, :]
+    qb = jnp.where(own[None, :, :, None], q[:, :, None, :], 0)
     out = paged_decode_attention_hp(
-        q[:, 0], kp, vp, tables.astype(jnp.int32),
-        lengths.astype(jnp.int32), interpret=it)
-    return out[:, None]
+        to_pool_rows(qb, w), to_pool_rows(k_new[:, None], w),
+        to_pool_rows(v_new[:, None], w), k_pool, v_pool,
+        layer, tables, lengths, scale=1.0 / math.sqrt(hd),
+        pages_per_block=ppb, interpret=interpret_mode(interpret))
+    # each head's output sits in its own kv head's columns
+    out = out[:, :, :hkv * hd].reshape(b, h, hkv, hd)
+    return jnp.sum(jnp.where(own[None, :, :, None], out, 0), axis=2)

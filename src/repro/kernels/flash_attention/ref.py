@@ -33,33 +33,35 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
         .astype(q.dtype)
 
 
-def paged_decode_attention_ref(q, k_pages, v_pages, tables,
-                               lengths) -> jnp.ndarray:
-    """Oracle for the paged decode op: gather the page table into a dense
-    cache view, then mask-and-softmax exactly like dense decode.
-    q (B, 1, H, hd); k_pages/v_pages (P, ps, Hkv, hd);
-    tables (B, npp) i32; lengths (B,) i32 (incl. the current token)."""
-    b, _, h, hd = q.shape
+def paged_decode_attention_ref(q, k_new, v_new, k_pool, v_pool, layer,
+                               tables, lengths) -> jnp.ndarray:
+    """Oracle for the paged decode op: gather the layer's pages into a
+    dense cache view, append the current token, and mask-and-softmax like
+    dense decode.  q (B, H, hd); k_new/v_new (B, Hkv, hd);
+    k_pool/v_pool (L, P, ps, W >= Hkv*hd); layer int; tables (B, npp) i32;
+    lengths (B,) i32 tokens in the pool (the current token excluded)."""
+    b, h, hd = q.shape
+    hkv = k_new.shape[1]
     npp = tables.shape[1]
-    ps = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    ps = k_pool.shape[2]
 
-    def dense(pages):                      # (B, npp*ps, Hkv, hd)
-        g = jnp.take(pages, tables, axis=0)
-        return g.reshape(b, npp * ps, hkv, hd)
+    def dense(pool, new):                  # (B, npp*ps + 1, Hkv, hd)
+        g = jnp.take(pool[layer], tables, axis=0)[..., :hkv * hd]
+        g = g.reshape(b, npp * ps, hkv, hd)
+        return jnp.concatenate([g, new[:, None].astype(g.dtype)], axis=1)
 
-    k, v = dense(k_pages), dense(v_pages)
+    k, v = dense(k_pool, k_new), dense(v_pool, v_new)
     group = h // hkv
     if group > 1:
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
-    s = jnp.einsum("bqhd,bchd->bhqc", q.astype(jnp.float32),
+    s = jnp.einsum("bhd,bchd->bhc", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(hd)
-    kpos = jnp.arange(npp * ps)[None, :]
-    mask = kpos < lengths[:, None]
-    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+    kpos = jnp.arange(npp * ps + 1)[None, :]
+    mask = (kpos < lengths[:, None]) | (kpos == npp * ps)
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqc,bchd->bqhd", p,
+    return jnp.einsum("bhc,bchd->bhd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 

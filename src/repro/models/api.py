@@ -69,14 +69,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
-                     dtype=None):
+                     dtype=None, *, rows: bool = True):
     """Paged KV page pools (transformer-only — the serving engine falls
     back to the dense cache for every other family)."""
     if cfg.family != "transformer":
         raise NotImplementedError(
             f"paged KV cache is transformer-only, not {cfg.family}")
     return family_module(cfg).init_paged_cache(cfg, num_pages, page_size,
-                                               dtype)
+                                               dtype, rows=rows)
 
 
 def decode_step(cfg: ModelConfig, params: Params, tokens, cache):

@@ -465,13 +465,17 @@ def layer_fwd(cfg: ModelConfig, kind: str, p: Params, x, positions,
               mrope_positions=None):
     a, kv = attn_block(cfg, p["attn"], apply_norm(cfg, p["norm1"], x),
                        positions, mrope_positions)
-    # fused norm_impl runs the attn-residual add + norm2 as one kernel
+    return _ffn_residual(cfg, kind, p, x, a), kv
+
+
+def _ffn_residual(cfg: ModelConfig, kind: str, p: Params, x, a):
+    """The layer after its attention: residual add + norm2 (one kernel
+    under the fused norm_impl), then the MLP or MoE block and its
+    residual.  Shared by every forward and decode path."""
     x, h = apply_norm_residual(cfg, p["norm2"], x, a)
     if kind == "moe":
-        x = x + moe_block(cfg, p["moe"], h)
-    else:
-        x = x + mlp_block(cfg, p["mlp"], h)
-    return x, kv
+        return x + moe_block(cfg, p["moe"], h)
+    return x + mlp_block(cfg, p["mlp"], h)
 
 
 # ---------------------------------------------------------------------------
@@ -633,14 +637,26 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
-                     dtype=None) -> list:
+                     dtype=None, *, rows: bool = True) -> list:
     """Per-segment KV page pools: the paged analogue of `init_cache`'s
     (L, B, C, ...) slabs with the (B, C) rectangle replaced by a shared
-    (num_pages, page_size) pool.  Page 0 is reserved as the null page
-    every unused page-table entry points at; its contents are never read
-    (decode masks by per-slot length).  Slot ownership / page tables live
-    with the serving engine (`repro.serving.paged.PagePool`)."""
+    (num_pages, page_size) pool.  K and V pools are (L, P, ps, W): a
+    position's kv heads side by side in one row of the paged decode
+    kernel's layout (`flash_attention.kernel.pool_row_width`/`to_pool_rows`),
+    so one page of one layer is one contiguous (ps, W) slab that the
+    kernel reads in place.  `rows=False` keeps the heads on their own
+    axis, (L, P, ps, kvh, hd): the layout of the int8 pool, whose per-head
+    scales broadcast over it, and of a pool whose kv heads shard over a
+    mesh.  MLA latents are (L, P, ps, D) either way.
+    Page 0 is reserved as the null page every unused page-table entry
+    points at; its contents are never read (decode masks by per-slot
+    length).  Slot ownership / page tables live with the serving engine
+    (`repro.serving.paged.PagePool`)."""
+    from repro.kernels.flash_attention.kernel import pool_row_width
+
     dt = dtype or cfg.jdtype
+    tail = (pool_row_width(cfg.kv_heads, cfg.hd),) if rows \
+        else (cfg.kv_heads, cfg.hd)
     segs = []
     for kind, count in layer_segments(cfg):
         if cfg.use_mla:
@@ -648,12 +664,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                 (count, num_pages, page_size,
                  cfg.mla_kv_rank + cfg.mla_rope_dim), dt)})
         else:
-            segs.append({
-                "k": jnp.zeros((count, num_pages, page_size,
-                                cfg.kv_heads, cfg.hd), dt),
-                "v": jnp.zeros((count, num_pages, page_size,
-                                cfg.kv_heads, cfg.hd), dt),
-            })
+            shape = (count, num_pages, page_size) + tail
+            segs.append({"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)})
     return segs
 
 
@@ -718,19 +730,9 @@ def _decode_attn(cfg: ModelConfig, p: Params, x, seg_cache, index):
         o_lat = jnp.einsum("bhqc,bck->bqhk", probs, lat.astype(dt))
         wuv = p["wuv"].astype(dt).reshape(kvr, cfg.n_heads, hd)
         o = jnp.einsum("bqhk,khd->bqhd", o_lat, wuv)
-        out = o.reshape(bsz, 1, cfg.q_dim) @ p["wo"].astype(dt)
-        return out, {"latent": cache}
+        return _attn_out(cfg, p, o), {"latent": cache}
 
-    q = x @ p["wq"].astype(dt)
-    k = x @ p["wk"].astype(dt)
-    v = x @ p["wv"].astype(dt)
-    if cfg.qkv_bias:
-        q, k, v = q + p["bq"].astype(dt), k + p["bk"].astype(dt), \
-            v + p["bv"].astype(dt)
-    q = q.reshape(bsz, 1, cfg.n_heads, cfg.hd)
-    k = k.reshape(bsz, 1, cfg.kv_heads, cfg.hd)
-    v = v.reshape(bsz, 1, cfg.kv_heads, cfg.hd)
-    q, k = _rope_qk(cfg, q, k, pos1)
+    q, k, v = _decode_qkv(cfg, p, x, pos1)
     K, V = seg_cache["k"], seg_cache["v"]           # (B, C, kvh, hd)
     clen = K.shape[1]
     slot = _ring_slot(cfg, index, clen)
@@ -764,8 +766,31 @@ def _decode_attn(cfg: ModelConfig, p: Params, x, seg_cache, index):
         scores = jnp.where(mask[:, None, None, :], scores, -1e30)
         probs = jax.nn.softmax(scores, -1).astype(dt)
         o = jnp.einsum("bhqc,bchd->bqhd", probs, Vr)
-    out = o.reshape(bsz, 1, cfg.q_dim) @ p["wo"].astype(dt)
-    return out, {"k": K, "v": V}
+    return _attn_out(cfg, p, o), {"k": K, "v": V}
+
+
+def _decode_qkv(cfg: ModelConfig, p: Params, x, pos1):
+    """The current token's projections, qkv bias and rotary: q
+    (B, 1, H, hd), k and v (B, 1, kvh, hd).  Shared by the dense and
+    the paged decode steps."""
+    bsz = x.shape[0]
+    dt = cfg.jdtype
+    q = x @ p["wq"].astype(dt)
+    k = x @ p["wk"].astype(dt)
+    v = x @ p["wv"].astype(dt)
+    if cfg.qkv_bias:
+        q, k, v = q + p["bq"].astype(dt), k + p["bk"].astype(dt), \
+            v + p["bv"].astype(dt)
+    q = q.reshape(bsz, 1, cfg.n_heads, cfg.hd)
+    k = k.reshape(bsz, 1, cfg.kv_heads, cfg.hd)
+    v = v.reshape(bsz, 1, cfg.kv_heads, cfg.hd)
+    q, k = _rope_qk(cfg, q, k, pos1)
+    return q, k, v
+
+
+def _attn_out(cfg: ModelConfig, p: Params, o):
+    """Per-head attention output (B, 1, H, hd) -> the output projection."""
+    return o.reshape(o.shape[0], 1, cfg.q_dim) @ p["wo"].astype(cfg.jdtype)
 
 
 def _decode_layer(cfg: ModelConfig, kind: str, p: Params, x, seg_cache,
@@ -773,12 +798,7 @@ def _decode_layer(cfg: ModelConfig, kind: str, p: Params, x, seg_cache,
     a, new_cache = _decode_attn(cfg, p["attn"],
                                 apply_norm(cfg, p["norm1"], x),
                                 seg_cache, index)
-    x, h = apply_norm_residual(cfg, p["norm2"], x, a)
-    if kind == "moe":
-        x = x + moe_block(cfg, p["moe"], h)
-    else:
-        x = x + mlp_block(cfg, p["mlp"], h)
-    return x, new_cache
+    return _ffn_residual(cfg, kind, p, x, a), new_cache
 
 
 def decode_step(cfg: ModelConfig, params: Params, tokens, cache):
@@ -814,6 +834,56 @@ def decode_step(cfg: ModelConfig, params: Params, tokens, cache):
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params, x)
     return logits, {"segments": new_segs, "index": raw_index + 1}
+
+
+def paged_decode_step(cfg: ModelConfig, params: Params, tokens,
+                      pool_segments, tables, lengths):
+    """One decode step that reads K/V in place from the page pool.
+
+    tokens (n, 1) int32; pool_segments: the per-segment K/V row pools of
+    `init_paged_cache`, (L, P, ps, W); tables (n, pages_per_slot) int32
+    physical page ids; lengths (n,) int32 tokens each lane holds in the
+    pool, so the new token sits at position lengths[b].  Attention runs
+    through `paged_decode_attention`, which reads the pool through the
+    tables and folds in the new token's own K/V; no dense cache view is
+    built and no grouped head is repeated.  The pool is a read-only
+    operand of the layer loop: the new token's K/V of every layer come
+    back as new_kv, one {"k", "v"} of (L, n, kvh, hd) per segment, for
+    the caller to write into page tables[b, lengths[b] // ps] at offset
+    lengths[b] % ps.  Returns (logits (n, 1, V), new_kv)."""
+    from repro.kernels.flash_attention.ops import paged_decode_attention
+
+    pos1 = lengths[:, None].astype(jnp.int32)
+    x = embed_tokens(cfg, params, tokens)
+    new_kv = []
+    for seg, pool in zip(params["segments"], pool_segments):
+        kind = segment_kind(seg)
+        sp = segment_params(seg)
+        count = jax.tree_util.tree_leaves(sp)[0].shape[0]
+
+        def body(h, xs):
+            lp, layer = xs
+            q, k, v = _decode_qkv(cfg, lp["attn"],
+                                  apply_norm(cfg, lp["norm1"], h), pos1)
+            o = paged_decode_attention(q[:, 0], k[:, 0], v[:, 0], pool["k"],
+                                       pool["v"], layer, tables, lengths)
+            a = _attn_out(cfg, lp["attn"], o[:, None])
+            return _ffn_residual(cfg, kind, lp, h, a), \
+                {"k": k[:, 0], "v": v[:, 0]}
+
+        layers = jnp.arange(count, dtype=jnp.int32)
+        if cfg.scan_layers and count >= cfg.scan_min_layers:
+            x, kv = jax.lax.scan(body, x, (sp, layers))
+        else:
+            kvs = []
+            for i in range(count):
+                lp = jax.tree.map(lambda a: a[i], sp)
+                x, kvi = body(x, (lp, layers[i]))
+                kvs.append(kvi)
+            kv = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
+        new_kv.append(kv)
+    x = apply_norm(cfg, params["final_norm"], x)
+    return unembed(cfg, params, x), new_kv
 
 
 def _window_attn(cfg: ModelConfig, p: Params, x, seg_cache, pos):
@@ -889,11 +959,7 @@ def decode_window(cfg: ModelConfig, params: Params, tokens, cache):
             lc = jax.tree.map(lambda a: a[i], seg_cache)
             a, nci = _window_attn(cfg, lp["attn"],
                                   apply_norm(cfg, lp["norm1"], x), lc, pos)
-            x, h = apply_norm_residual(cfg, lp["norm2"], x, a)
-            if kind == "moe":
-                x = x + moe_block(cfg, lp["moe"], h)
-            else:
-                x = x + mlp_block(cfg, lp["mlp"], h)
+            x = _ffn_residual(cfg, kind, lp, x, a)
             ncs.append(nci)
         new_segs.append(jax.tree.map(lambda *xs: jnp.stack(xs), *ncs))
     x = apply_norm(cfg, params["final_norm"], x)

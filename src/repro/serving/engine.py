@@ -30,11 +30,13 @@ allocated on admission/growth and freed on finish — HBM holds live
 tokens, not `max_batch x max_len` rectangles.  Prefill pads prompts to
 power-of-two BUCKETS so an arbitrary prompt-length mix compiles at most
 `len(engine.buckets)` prefill executables plus one decode executable.
-Decode gathers the active slots' pages into the dense layout
-`decode_step` expects and scatters back, so paged decode is bit-exact
-against the dense cache.  When the free list runs dry the engine
-preempts the youngest-admitted slot (requeued at the queue front and
-later resumed by re-prefilling its tokens).  `paged=False` (or
+Decode reads each active slot's live pages in place through its page
+table (`transformer.paged_decode_step`) and writes only the new token's
+K/V back, token-exact against the dense cache; the int8 pool, MLA
+latents and pools over a mesh of more than one device gather pages into
+the dense layout `decode_step` expects and scatter back.  When the free
+list runs dry the engine preempts the youngest-admitted slot (requeued
+at the queue front and later resumed by re-prefilling its tokens).  `paged=False` (or
 `MOZART_PAGED_KV=0`) restores the dense rectangles.  `MOZART_KV_QUANT`
 stores KV int8 with per-head scales (`serving.quant`): any truthy value
 quantizes the paged pool (gather dequantizes, scatter re-quantizes, the
@@ -228,7 +230,7 @@ class ServingEngine:
                 mcfg, max_batch, max_len, decode_batch=self.decode_batch,
                 compact=self.compact, page_size=ps, num_pages=num_pages,
                 bucket_min=knobs.get_int("MOZART_PREFILL_BUCKET_MIN"),
-                quantized=self.kv_quant)
+                quantized=self.kv_quant, mesh=mesh)
         elif mcfg.family == "whisper":
             self.state = state_mod.CrossAttnState(
                 mcfg, max_batch, max_len, decode_batch=self.decode_batch,
@@ -257,9 +259,7 @@ class ServingEngine:
         self._decode = _decode_fn(mcfg)
         self._prefill = state_mod._whisper_prefill_fn(mcfg, max_len) \
             if mcfg.family == "whisper" else _prefill_fn(mcfg, max_len)
-        self._paged_decode = \
-            paged_kv.paged_decode_fn(mcfg, self.kv_quant) if self.paged \
-            else None
+        self._paged_decode = self.state.decode_fn() if self.paged else None
         self.stats = {"decode_steps": 0, "prefills": 0,
                       "tokens_out": 0, "live_slot_steps": 0,
                       "preemptions": 0, "rejected": 0,
@@ -495,8 +495,11 @@ class ServingEngine:
         Returns False when the NaN guard swallowed the step.  Subclasses
         (spec-decode) replace this with multi-token propose/verify."""
         fn = self._paged_decode if self.paged else self._decode
+        pages = {"pages": functools.partial(self.state.pages_read, active)} \
+            if self.paged else {}
         with spans.span("decode", active=len(active),
-                        ctx=functools.partial(self._live_positions, active)):
+                        ctx=functools.partial(self._live_positions, active),
+                        **pages):
             logits, lane = self.state.decode(fn, self.params,
                                              self.next_token, active)
         if self.guard_nan:

@@ -7,10 +7,13 @@ length.  This module replaces both:
 * **Pages** — KV lives in per-layer pools of fixed-size pages
   (`models.api.init_paged_cache`); each slot owns a list of physical
   pages recorded in a per-slot page table, so HBM holds live tokens, not
-  rectangles.  Page 0 is a reserved null page: every unused table entry
-  points at it and its contents are never read (attention masks by
-  per-slot length).  Allocation/free is host-side free-list accounting
-  (`PagePool`), cheap and exact.
+  rectangles.  In the pool the in-place decode reads, a K or V page of
+  one layer is one contiguous (ps, W) slab: a position's kv heads side by
+  side in a lane-aligned row.  Page 0 is a
+  reserved null page: every unused table entry points at it and its
+  contents are never read (attention masks by per-slot length).
+  Allocation/free is host-side free-list accounting (`PagePool`), cheap
+  and exact.
 * **Bucketed prefill** — prompts are right-padded to the next
   power-of-two bucket, so an arbitrary prompt mix compiles at most
   `len(prefill_buckets(...))` prefill executables.  Causal attention
@@ -20,24 +23,34 @@ length.  This module replaces both:
   redirected to the null page inside the trace, so bucket padding never
   occupies — or pollutes — pages past the true prompt length; a page's
   only nonzero contents are real KV.
+* **Decode in place** — `paged_decode_fn` runs
+  `transformer.paged_decode_step`, whose attention kernel reads each
+  lane's live pages straight from the pool through its table row and
+  folds in the new token's own K/V; after the layer loop one scatter
+  writes the new token's K/V of every layer into its page.  No dense
+  sub-cache is built, no page is copied, and a lane reads only the pages
+  it holds (padding lanes hold none).
+* **Gathered decode** — the int8 pool, MLA latent pools and pools over a
+  mesh of more than one device keep the gather path: their K/V keep the
+  kv heads on their own axis (the axis a mesh shards), and the decode
+  gathers the selected slots' pages into the dense `(n, C, ...)` layout
+  `transformer.decode_step` understands, runs it, and scatters the
+  advanced pages back (`kv_gather`/`kv_scatter`).
 * **Int8 quantization** — with `quant=True` (`MOZART_KV_QUANT=1`) pages
-  are stored int8 with per-(layer, page, kv-head) float32 scales
-  (`serving.quant`): gather dequantizes into the f32 dense sub-cache the
-  unchanged decode math runs over, scatter re-quantizes with fresh
-  scales, and positions at or past each slot's length are zeroed before
+  are stored int8, heads on their own axis, with per-(layer, page,
+  kv-head) float32 scales (`serving.quant`): gather dequantizes into the
+  f32 dense sub-cache, scatter re-quantizes with fresh scales, and
+  positions at or past each slot's length are zeroed before
   re-quantization so stale garbage in reused pages can never inflate a
-  scale and crush the live tokens' resolution.  Same decode loop, ~4x
-  the slots per HBM byte (`quant.pages_for_byte_budget`).
+  scale and crush the live tokens' resolution.  ~4x the slots per HBM
+  byte (`quant.pages_for_byte_budget`).
 
-Decode gathers the selected slots' pages into the dense `(n, C, ...)`
-layout `transformer.decode_step` already understands, runs the unchanged
-decode math, and scatters the advanced pages back — so paged decode is
-bit-identical to the dense cache path.  Page tables and per-slot lengths
-live as host `numpy` arrays and enter the jitted functions as plain
-array arguments: every step passes the same shapes, so steady-state
-serving dispatches zero fresh compiles no matter how tables churn.  The
-jitted builders are module-level and `lru_cache`'d per config, so
-engines sharing a config reuse one trace cache.
+Page tables and per-slot lengths live as host `numpy` arrays and enter
+the jitted functions as plain array arguments: every step passes the
+same shapes, so steady-state serving dispatches zero fresh compiles no
+matter how tables churn.  The functions that make the jitted programs
+are module-level and `lru_cache`'d per config, so engines sharing a
+config reuse one trace cache.
 """
 
 from __future__ import annotations
@@ -48,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_attention.ops import to_pool_rows
 from repro.models import api, transformer
 from repro.models.config import ModelConfig
 
@@ -91,6 +105,7 @@ class PagePool:
         num_pages: int | None = None,
         dtype=None,
         quant: bool = False,
+        rows: bool = True,
     ):
         if page_size < 1 or page_size & (page_size - 1):
             raise ValueError(f"page_size must be a power of two, got {page_size}")
@@ -105,10 +120,13 @@ class PagePool:
             raise ValueError("need at least one allocatable page beyond the null page")
         # quant: int8 pages + per-(layer, page, kv-head) f32 scales; the
         # prefill/decode builders below dequantize on gather and
-        # re-quantize on scatter (serving.quant)
+        # re-quantize on scatter (serving.quant).  rows: K/V in the row
+        # layout the in-place decode reads; the int8 pool, and a pool the
+        # caller shards over a mesh, keep the kv heads on their own axis
         self.quant = quant
         self.segments = api.init_paged_cache(
-            mcfg, self.num_pages, page_size, jnp.int8 if quant else dtype
+            mcfg, self.num_pages, page_size, jnp.int8 if quant else dtype,
+            rows=rows and not quant,
         )
         self.scales = kvq.scale_struct(self.segments) if quant else None
         # tables/index are HOST state (numpy): they enter jitted code as
@@ -118,7 +136,15 @@ class PagePool:
         self.index = np.zeros((max_batch,), np.int32)
         self._free = list(range(self.num_pages - 1, 0, -1))  # pop() allocates ascending
         self._owned: list[list[int]] = [[] for _ in range(max_batch)]
-        self.stats = {"page_allocs": 0, "page_frees": 0, "peak_pages_in_use": 0}
+        # kv_pages_read / kv_pages_capacity: the share of the lanes' page
+        # capacity the decodes read (PagedKVState.pages_read)
+        self.stats = {
+            "page_allocs": 0,
+            "page_frees": 0,
+            "peak_pages_in_use": 0,
+            "kv_pages_read": 0,
+            "kv_pages_capacity": 0,
+        }
 
     @property
     def free_pages(self) -> int:
@@ -191,9 +217,8 @@ def _gather_pages(segments, tables_sel):
 
 def _scatter_pages(segments, dense, tables_sel):
     """Write an advanced dense sub-cache back through the page tables.
-    Duplicate physical ids only occur for padding lanes (identical
-    content) and the never-read null page, so scatter order is
-    irrelevant."""
+    Duplicate physical ids only occur for the never-read null page, so
+    scatter order is irrelevant."""
     n, npp = tables_sel.shape
 
     def leaf(a, d):  # a: (L, P, ps, ...); d: (L, n, C, ...)
@@ -201,6 +226,29 @@ def _scatter_pages(segments, dense, tables_sel):
         return a.at[:, tables_sel].set(dp.astype(a.dtype))
 
     return jax.tree.map(leaf, segments, dense)
+
+
+def _write_token(segments, new_kv, tables_sel, index_sel):
+    """Write each lane's new token's K/V of every layer (new_kv: per
+    segment {"k", "v"} of (L, n, kvh, hd)) into its page:
+    tables_sel[b, index_sel[b] // ps] at offset index_sel[b] % ps.  A
+    padding lane (null table row, length 0) writes into the null page."""
+    ps = segments[0]["k"].shape[2]
+    pages = jnp.take_along_axis(tables_sel, (index_sel // ps)[:, None], axis=1)[:, 0]
+    offs = index_sel % ps
+
+    def write(a, kv):  # a: (L, P, ps, W); kv: (L, n, kvh, hd)
+        # one (layer, page, offset) index per row keeps every update a
+        # contiguous W-wide row of the pool's own layout: the scatter runs
+        # in place instead of relaying the whole pool out around it
+        layer = jnp.arange(a.shape[0])[:, None]
+        rows = to_pool_rows(kv, a.shape[-1]).astype(a.dtype)
+        return a.at[layer, pages[None, :], offs[None, :]].set(rows)
+
+    return [
+        {name: write(a, kv[name]) for name, a in seg.items()}
+        for seg, kv in zip(segments, new_kv)
+    ]
 
 
 def _gather_pages_dequant(segments, scales, tables_sel):
@@ -248,21 +296,25 @@ def _scatter_pages_quant(segments, scales, dense, tables_sel, new_len):
 
 @functools.lru_cache(maxsize=8)
 def paged_decode_fn(mcfg: ModelConfig, quantized: bool = False):
-    """Jitted gather -> decode -> scatter over the page pool.  One
-    executable per (config, selection width); the pool buffers are
-    donated so the scatter updates in place.  Slot lengths advance on the
-    host (the caller knows exactly which slots stepped), so only logits
-    and the pool round-trip the device.  The quantized variant takes and
-    returns the scale tree alongside the int8 pool."""
+    """The jitted paged decode for a config, one executable per
+    selection width; the pool buffers are donated so the writes update
+    them in place.  Slot lengths advance on the host (the caller knows
+    exactly which slots stepped), so only logits and the pool round-trip
+    the device.
+
+    The bf16/f32 program decodes a row-layout K/V pool in place
+    (`transformer.paged_decode_step`, then `kv_write` puts the new token's
+    K/V into its page).  The int8 program (which takes and returns the
+    scale tree alongside the codes) gathers and re-quantizes.  Pools that
+    neither reads take `gathered_decode_fn` (`PagedKVState.decode_fn`
+    picks)."""
 
     def paged_decode(params, tokens, segments, tables_sel, index_sel):
-        with jax.named_scope("kv_gather"):
-            dense = _gather_pages(segments, tables_sel)
-        logits, new = api.decode_step(
-            mcfg, params, tokens, {"segments": dense, "index": index_sel}
+        logits, new_kv = transformer.paged_decode_step(
+            mcfg, params, tokens, segments, tables_sel, index_sel
         )
-        with jax.named_scope("kv_scatter"):
-            segments = _scatter_pages(segments, new["segments"], tables_sel)
+        with jax.named_scope("kv_write"):
+            segments = _write_token(segments, new_kv, tables_sel, index_sel)
         return logits, segments
 
     def paged_decode_int8(params, tokens, segments, scales, tables_sel, index_sel):
@@ -279,6 +331,27 @@ def paged_decode_fn(mcfg: ModelConfig, quantized: bool = False):
 
     if quantized:
         return jax.jit(paged_decode_int8, donate_argnums=(2, 3))
+    return jax.jit(paged_decode, donate_argnums=(2,))
+
+
+@functools.lru_cache(maxsize=8)
+def gathered_decode_fn(mcfg: ModelConfig):
+    """Jitted gather -> `decode_step` -> scatter over the page pool: the
+    decode of an MLA latent pool, and of a K/V pool that keeps its kv
+    heads on their own axis to shard them over a mesh (the paged kernel
+    reads one device's pool).  Same arguments and donation as
+    `paged_decode_fn`'s."""
+
+    def paged_decode(params, tokens, segments, tables_sel, index_sel):
+        with jax.named_scope("kv_gather"):
+            dense = _gather_pages(segments, tables_sel)
+        logits, new = api.decode_step(
+            mcfg, params, tokens, {"segments": dense, "index": index_sel}
+        )
+        with jax.named_scope("kv_scatter"):
+            segments = _scatter_pages(segments, new["segments"], tables_sel)
+        return logits, segments
+
     return jax.jit(paged_decode, donate_argnums=(2,))
 
 
@@ -328,12 +401,15 @@ def paged_prefill_fn(
         last = jax.lax.dynamic_slice_in_dim(logits, plen - 1, 1, axis=1)
         kv_trees, page_live = _masked_kv(plen, kvs)
         row = jnp.where(page_live, table_row, 0)
+
+        def write(a, kv):
+            pages = _pages(kv)
+            if pages.ndim > a.ndim:  # heads folded into the pool's rows
+                pages = to_pool_rows(pages, a.shape[-1])
+            return a.at[:, row].set(pages.astype(a.dtype))
+
         new_segs = [
-            jax.tree.map(
-                lambda a, kv: a.at[:, row].set(_pages(kv).astype(a.dtype)),
-                seg_pool,
-                kv_tree,
-            )
+            jax.tree.map(write, seg_pool, kv_tree)
             for seg_pool, kv_tree in zip(segments, kv_trees)
         ]
         return last, new_segs
@@ -365,7 +441,7 @@ def paged_prefill_fn(
 
 
 def paged_supported(mcfg: ModelConfig) -> bool:
-    """Paged + bucketed serving is exact only where the gather/bucket
+    """Paged + bucketed serving is exact only where the page/bucket
     assumptions hold: the transformer cache layout, no sliding-window
     ring (pages map positions, not ring slots), and no MoE (pad tokens
     would consume router capacity and perturb real tokens)."""

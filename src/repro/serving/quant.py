@@ -83,7 +83,9 @@ def kv_page_nbytes(mcfg, page_size: int, quant: bool) -> int:
     from repro.models import api
 
     segs = jax.eval_shape(
-        lambda: api.init_paged_cache(mcfg, 1, page_size, jnp.int8 if quant else None)
+        lambda: api.init_paged_cache(
+            mcfg, 1, page_size, jnp.int8 if quant else None, rows=not quant
+        )
     )
     total = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(segs))
     if quant:

@@ -16,7 +16,9 @@ the model zoo:
   runs the unchanged f32 math, zeroes stale positions, and re-quantizes
   with fresh scales, all inside ONE jitted executable.
 * `PagedKVState`   — the block-paged pool (`serving.paged.PagePool`),
-  bucketed prefill, and the gathered paged decode (optionally int8).
+  bucketed prefill, and the paged decode: in place through the page
+  tables, or gathered for the int8 pool, MLA latents and pools over a
+  mesh of more than one device.
 * `RecurrentState` — rglru conv+hidden / rwkv6 wkv state
   ({"layers": [(B, ...)], "index": (B,)}).  Recurrent state advances
   IRREVERSIBLY (there is no per-position cache to rewind), so decode is
@@ -326,7 +328,7 @@ class DenseKVState:
 # -- block-paged transformer pool ---------------------------------------------
 
 class PagedKVState:
-    """Block-paged KV: PagePool + bucketed prefill + gathered decode."""
+    """Block-paged KV: PagePool + bucketed prefill + paged decode."""
 
     kind = "paged"
     paged = True
@@ -335,16 +337,23 @@ class PagedKVState:
     def __init__(self, mcfg: ModelConfig, max_batch: int, max_len: int, *,
                  decode_batch: int, compact: bool, page_size: int,
                  num_pages: int | None, bucket_min: int,
-                 quantized: bool = False):
+                 quantized: bool = False, mesh=None):
         self.mcfg = mcfg
         self.max_batch = max_batch
         self.max_len = max_len
         self.decode_batch = decode_batch
         self.compact = compact
         self.quantized = quantized
+        # the one choice of decode path: a bf16/f32 K/V pool on one device
+        # is read in place by the paged kernel, from the row layout it
+        # reads; the int8 pool, MLA latents and a pool over a mesh of more
+        # than one device (`mesh`, the one `place` will get: the kernel
+        # reads one device's pool) keep heads on their own axis and gather
+        self.in_place = not quantized and not mcfg.use_mla \
+            and (mesh is None or mesh.size == 1)
         self.pool = paged_kv.PagePool(
             mcfg, max_batch, max_len, page_size=page_size,
-            num_pages=num_pages, quant=quantized)
+            num_pages=num_pages, quant=quantized, rows=self.in_place)
         self.buckets = paged_kv.prefill_buckets(max_len, bucket_min)
         self.capacity = paged_kv.pool_token_capacity(self.pool, max_len)
 
@@ -361,6 +370,12 @@ class PagedKVState:
                 self.pool.scales,
                 paged_cache_shardings(mesh, self.pool.scales,
                                       self.mcfg.kv_heads))
+
+    def decode_fn(self):
+        """The jitted decode this state's pool takes (`fn` of `decode`)."""
+        if self.in_place or self.quantized:
+            return paged_kv.paged_decode_fn(self.mcfg, self.quantized)
+        return paged_kv.gathered_decode_fn(self.mcfg)
 
     def prefill(self, fn, params, b: int, seq: np.ndarray, frames=None):
         """Bucket-padded prefill of `seq` into slot b's pages; returns
@@ -382,26 +397,47 @@ class PagedKVState:
         self.pool.index[b] = plen
         return last
 
+    def _width(self) -> int:
+        return self.decode_batch if self.compact else self.max_batch
+
+    def pages_read(self, active: list[int]) -> int:
+        """Pool pages one decode of `active` reads: each slot's pages up
+        to its length when the decode reads in place (the kernel may
+        also fetch the null tail of a slot's last block), every lane's
+        whole table row when it gathers."""
+        if not self.in_place:
+            return self._width() * self.pool.pages_per_slot
+        ps = self.pool.page_size
+        return int(sum(-(-int(self.pool.index[b]) // ps) for b in active))
+
     def decode(self, fn, params, next_token: np.ndarray, active: list[int]):
-        """One gathered decode over the page pool at a fixed lane width
-        (decode_batch when compacting, max_batch for the full-width
-        emulation) — a single executable either way."""
-        width = self.decode_batch if self.compact else self.max_batch
-        sel = active + [active[0]] * (width - len(active))
-        tables_sel = self.pool.tables[np.asarray(sel)]
-        index_sel = self.pool.index[np.asarray(sel)]
+        """One paged decode at a fixed lane width (decode_batch when
+        compacting, max_batch for the full-width emulation) — a single
+        executable either way.  Padding lanes get the null table row and
+        length 0: they read nothing and write into the null page."""
+        width = self._width()
+        n = len(active)
+        act = np.asarray(active)
+        tables_sel = np.zeros((width, self.pool.pages_per_slot), np.int32)
+        tables_sel[:n] = self.pool.tables[act]
+        index_sel = np.zeros((width,), np.int32)
+        index_sel[:n] = self.pool.index[act]
+        toks = np.zeros((width, 1), np.int32)
+        toks[:n] = next_token[act]
+        self.pool.stats["kv_pages_read"] += self.pages_read(active)
+        self.pool.stats["kv_pages_capacity"] += width * self.pool.pages_per_slot
         if self.quantized:
             logits, self.pool.segments, self.pool.scales = fn(
-                params, jnp.asarray(next_token[sel]),
-                self.pool.segments, self.pool.scales, tables_sel, index_sel)
+                params, jnp.asarray(toks), self.pool.segments,
+                self.pool.scales, tables_sel, index_sel)
         else:
             logits, self.pool.segments = fn(
-                params, jnp.asarray(next_token[sel]),
-                self.pool.segments, tables_sel, index_sel)
+                params, jnp.asarray(toks), self.pool.segments, tables_sel,
+                index_sel)
         # page-table bookkeeping is host-side numpy: advance the lengths
         # here instead of round-tripping them through the device
-        self.pool.index[np.asarray(active)] += 1
-        return logits, _lane_map(sel)
+        self.pool.index[act] += 1
+        return logits, _lane_map(active)
 
     def release(self, b: int) -> None:
         self.pool.release(b)

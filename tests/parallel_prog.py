@@ -86,6 +86,13 @@ def check_serving_engine_tp_matches_single():
     def run(mesh_arg, decode_batch=None):
         eng = ServingEngine(CFG, params, max_batch=4, max_len=32,
                             decode_batch=decode_batch, mesh=mesh_arg)
+        if mesh_arg is not None:
+            # the paged kernel reads one device's pool: over the mesh the
+            # decode gathers, and the pool's kv heads shard over "model"
+            assert eng.paged and not eng.state.in_place
+            for leaf in jax.tree.leaves(eng.pool.segments):
+                assert leaf.ndim == 5 and leaf.sharding.spec[3] == "model", \
+                    leaf.sharding
         reqs = [Request(rid=i, prompt=p, max_new_tokens=5)
                 for i, p in enumerate(prompts)]
         for r in reqs:

@@ -150,7 +150,8 @@ def _wrapper_calls():
             z((1, 5, 3, 8)), z((1, 5, 3, 8)), z((1, 5, 3, 8))
         ),
         "paged_decode_attention": lambda: paged_decode_attention(
-            z((1, 1, 3, 8)), z((3, 2, 3, 8)), z((3, 2, 3, 8)), z((1, 2), i32), z((1,), i32)
+            z((1, 3, 8)), z((1, 3, 8)), z((1, 3, 8)), z((2, 3, 2, 128)), z((2, 3, 2, 128)),
+            z((), i32), z((1, 2), i32), z((1,), i32)
         ),
         "fused_mlp": lambda: fused_mlp(z((5, 24)), z((24, 40)), z((24, 40)), z((40, 24))),
         "fused_rmsnorm": lambda: fused_rmsnorm(z((5, 24)), z((24,))),

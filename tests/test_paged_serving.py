@@ -15,6 +15,7 @@ import pytest
 
 from repro.models import api
 from repro.models.config import ModelConfig
+from repro.kernels.flash_attention.ops import to_pool_rows
 from repro.serving import paged as paged_mod
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.paged import PagePool, bucket_for, prefill_buckets
@@ -178,9 +179,10 @@ def test_paged_matches_dense_on_fixed_seed_mix(tiny_params, compact):
 
 
 def test_paged_decode_gather_is_bit_identical(tiny_params):
-    """The decode path (gather -> decode_step -> scatter) is BIT-exact
-    against the dense cache, not just token-exact: copy one dense cache
-    into pool pages by hand and compare the decode logits bitwise."""
+    """The paged decode (pool read in place by the kernel, then the new
+    token's K/V written into its page) matches the dense cache within
+    float32 rounding: copy one dense cache into pool pages by hand and
+    compare the decode logits and the written K/V."""
     from repro.serving.engine import _decode_fn
     from repro.serving.paged import paged_decode_fn
 
@@ -200,20 +202,260 @@ def test_paged_decode_gather_is_bit_identical(tiny_params):
 
         def place(pages, dense):
             out = np.asarray(pages).copy()
+            rows = np.asarray(to_pool_rows(dense, pages.shape[-1]))
             for b in range(bsz):
                 for j, pg in enumerate(pool.owned(b)):
-                    out[:, pg] = dense[:, b, j * ps : (j + 1) * ps]
+                    out[:, pg] = rows[:, b, j * ps : (j + 1) * ps]
             return jnp.asarray(out)
 
         new_segs.append(jax.tree.map(place, seg_p, seg_d))
     pool.segments = new_segs
     tok = jnp.asarray([[7], [9]], jnp.int32)
     sel = np.asarray([0, 1])
-    logits_d, _ = _decode_fn(TINY)(tiny_params, tok, cache)
-    logits_p, _ = paged_decode_fn(TINY)(
+    logits_d, new_d = _decode_fn(TINY)(tiny_params, tok, cache)
+    logits_p, segs_p = paged_decode_fn(TINY)(
         tiny_params, tok, pool.segments, pool.tables[sel], pool.index[sel]
     )
-    np.testing.assert_array_equal(np.asarray(logits_d), np.asarray(logits_p))
+    np.testing.assert_allclose(
+        np.asarray(logits_p), np.asarray(logits_d), rtol=1e-5, atol=1e-5
+    )
+    for seg_d, seg_p in zip(new_d["segments"], segs_p):
+        for name in ("k", "v"):
+            rows = np.asarray(to_pool_rows(seg_d[name], seg_p[name].shape[-1]))
+            for b in range(bsz):
+                page = pool.owned(b)[33 // ps]
+                np.testing.assert_allclose(
+                    np.asarray(seg_p[name])[:, page, 33 % ps],
+                    rows[:, b, 33],
+                    rtol=1e-6,
+                    atol=1e-6,
+                )
+
+
+def _gqa_config(n_heads, kv_heads, head_dim, dtype="float32"):
+    return ModelConfig(
+        name=f"paged-{n_heads}-{kv_heads}-{head_dim}-{dtype}",
+        n_layers=2,
+        d_model=64,
+        n_heads=n_heads,
+        kv_heads=kv_heads,
+        head_dim=head_dim,
+        d_ff=96,
+        vocab=61,
+        qkv_bias=n_heads == 10,
+        dtype=dtype,
+        param_dtype=dtype,
+        scan_layers=False,
+    )
+
+
+# (query heads, kv heads, head_dim, dtype): groups 1, 2, 3 and 5 at head
+# widths 64 and 128, one in bf16
+GQA_CASES = [
+    (2, 2, 64, "float32"),
+    (4, 2, 128, "float32"),
+    (9, 3, 64, "float32"),
+    (10, 2, 128, "float32"),
+    (4, 2, 128, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("case", GQA_CASES, ids=lambda c: "-".join(map(str, c)))
+def test_paged_decode_step_matches_dense(case):
+    """The in-place paged decode against the dense `decode_step` over the
+    same KV: lanes of different lengths (one on a page boundary, one
+    whose new token starts a page), stale data in a freed page, past the
+    lengths and in the null page, padding lanes; and the new token's K/V
+    land in the right page and offset and nowhere else."""
+    from repro.models import transformer
+    from repro.serving.state import PagedKVState
+
+    n_heads, kv_heads, hd, dtype = case
+    cfg = _gqa_config(n_heads, kv_heads, hd, dtype)
+    params = api.init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(sum(case[:3]))
+    st = PagedKVState(cfg, 4, 64, decode_batch=4, compact=True, page_size=8,
+                      num_pages=None, bucket_min=16)
+    assert st.in_place
+    pool = st.pool
+    lengths = {0: 13, 1: 16, 2: 40}  # 16: a page boundary; 40: fills 5 pages
+    assert pool.ensure(3, 30)
+    pool.release(3)  # slot 3's pages are freed: stale data, never read
+    for b, n in lengths.items():
+        assert pool.ensure(b, n + 1)
+        pool.index[b] = n
+    # random KV everywhere, loud garbage in the null page
+    pool.segments = [
+        {
+            name: jnp.asarray(
+                rng.normal(size=a.shape) * np.where(np.arange(a.shape[1]) == 0, 1e3, 1.0)[
+                    None, :, None, None
+                ],
+                a.dtype,
+            )
+            for name, a in seg.items()
+        }
+        for seg in pool.segments
+    ]
+    before = [jax.tree.map(np.asarray, seg) for seg in pool.segments]
+    active = [2, 0, 1]  # one padding lane at width 4
+    nxt = rng.integers(1, cfg.vocab, size=(4, 1)).astype(np.int32)
+    logits, lane = st.decode(st.decode_fn(), params, nxt, active)
+
+    # dense reference over the very same pages
+    act = np.asarray(active)
+
+    def heads(a):  # (L, P, ps, W) rows -> (L, n, C, kvh, hd) of the lanes
+        g = a[:, pool.tables[act], :, : kv_heads * hd]
+        return jnp.asarray(g.reshape(a.shape[0], len(active), -1, kv_heads, hd))
+
+    dense = [jax.tree.map(heads, seg) for seg in before]
+    idx = np.asarray([lengths[b] for b in active], np.int32)
+    want, new = transformer.decode_step(
+        cfg, params, jnp.asarray(nxt[act]), {"segments": dense, "index": jnp.asarray(idx)}
+    )
+    tol = 5e-2 if dtype == "bfloat16" else 1e-4
+    got = np.asarray(logits, np.float32)[[lane[b] for b in active]]
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), rtol=tol, atol=tol)
+    for seg_b, seg_a, seg_n in zip(before, pool.segments, new["segments"]):
+        for name in ("k", "v"):
+            after = np.asarray(seg_a[name]).copy()
+            rows = np.asarray(to_pool_rows(seg_n[name], after.shape[-1]), np.float32)
+            for j, b in enumerate(active):
+                page = pool.tables[b, lengths[b] // 8]
+                off = lengths[b] % 8
+                np.testing.assert_allclose(
+                    after[:, page, off].astype(np.float32), rows[:, j, lengths[b]],
+                    rtol=tol, atol=tol,
+                )
+                after[:, page, off] = seg_b[name][:, page, off]
+            # nothing else moved, apart from the padding lane's null-page write
+            after[:, 0, 0] = seg_b[name][:, 0, 0]
+            np.testing.assert_array_equal(after, seg_b[name])
+
+
+MLA = ModelConfig(
+    name="tiny-paged-mla", n_layers=2, d_model=32, n_heads=4, kv_heads=4,
+    head_dim=8, d_ff=64, vocab=61, mla_q_rank=16, mla_kv_rank=16,
+    mla_rope_dim=8, dtype="float32", param_dtype="float32", scan_layers=False,
+)
+
+
+@pytest.mark.parametrize("cfg", [MLA, TINY], ids=["mla-latents", "kv-heads-axis"])
+def test_gathered_decode_is_bit_identical(cfg):
+    """Where the gather path remains (an MLA latent pool, and a K/V pool
+    with the kv heads on their own axis, as a mesh shards it), paged
+    decode is BIT-exact against the dense cache: copy one dense cache into
+    pool pages by hand, gather -> decode_step -> scatter, compare
+    bitwise."""
+    from repro.serving.engine import _decode_fn
+    from repro.serving.paged import gathered_decode_fn
+
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    max_len, bsz, n = 64, 2, 21
+    toks = jnp.asarray(rng.integers(1, cfg.vocab - 1, size=(bsz, n)), jnp.int32)
+    _, cache = api.prefill(cfg, params, {"tokens": toks}, max_len)
+    cache = {"segments": cache["segments"], "index": jnp.full((bsz,), n, jnp.int32)}
+    pool = PagePool(cfg, max_batch=bsz, max_len=max_len, page_size=16, rows=False)
+    ps = pool.page_size
+    for b in range(bsz):
+        assert pool.ensure(b, n + 1)
+        pool.index[b] = n
+
+    def place(pages, dense):
+        out = np.asarray(pages).copy()
+        for b in range(bsz):
+            for j, pg in enumerate(pool.owned(b)):
+                out[:, pg] = np.asarray(dense)[:, b, j * ps : (j + 1) * ps]
+        return jnp.asarray(out)
+
+    segs = [jax.tree.map(place, seg_p, seg_d)
+            for seg_d, seg_p in zip(cache["segments"], pool.segments)]
+    tok = jnp.asarray([[7], [9]], jnp.int32)
+    sel = np.asarray([0, 1])
+    want, _ = _decode_fn(cfg)(params, tok, cache)
+    got, _ = gathered_decode_fn(cfg)(params, tok, segs, pool.tables[sel], pool.index[sel])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize(
+    "cfg, quantized, devices, in_place",
+    [(TINY, False, None, True), (TINY, False, 1, True), (TINY, False, 4, False),
+     (TINY, True, None, False), (MLA, False, None, False)],
+    ids=["one-device", "one-device-mesh", "four-device-mesh", "int8", "mla-latents"],
+)
+def test_decode_path_follows_what_the_state_sees(cfg, quantized, devices, in_place):
+    """One decision picks the decode and the pool layout it reads: the
+    kernel reads a bf16/f32 K/V pool in rows on one device; the int8 pool,
+    MLA latents and a pool over a mesh of several devices keep their
+    layout and gather."""
+    from types import SimpleNamespace
+
+    from repro.serving.state import PagedKVState
+
+    # the state reads only the mesh's size; a real mesh of several devices
+    # is exercised in tests/parallel_prog.py
+    mesh = None if devices is None else SimpleNamespace(size=devices)
+    st = PagedKVState(cfg, 2, 32, decode_batch=2, compact=True, page_size=8,
+                      num_pages=None, bucket_min=16, quantized=quantized, mesh=mesh)
+    assert st.in_place is in_place
+    ndims = {a.ndim for a in jax.tree.leaves(st.pool.segments)}
+    if in_place:
+        assert st.decode_fn() is paged_mod.paged_decode_fn(cfg, False)
+        assert ndims == {4}  # (L, P, ps, W) rows
+    elif quantized:
+        assert st.decode_fn() is paged_mod.paged_decode_fn(cfg, True)
+        assert ndims == {5}
+    else:
+        assert st.decode_fn() is paged_mod.gathered_decode_fn(cfg)
+        assert ndims == ({4} if cfg.use_mla else {5})  # latents, or kv heads on axis 3
+
+
+@pytest.mark.parametrize("page_size", [4, 16])
+def test_in_place_decode_matches_dense_tokens(page_size):
+    """Engine-level greedy token equality, paged (read in place) vs dense,
+    for grouped heads: lengths cross page boundaries, slots churn, and the
+    compacted width leaves padding lanes."""
+    cfg = _gqa_config(6, 2, 16)
+    params = api.init_params(cfg, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(1, cfg.vocab - 1, size=n).astype(np.int32) for n in (3, 15, 16, 29, 7)]
+    outs = {}
+    for paged in (True, False):
+        eng = ServingEngine(cfg, params, max_batch=4, max_len=64, decode_batch=3,
+                            paged=paged, page_size=page_size)
+        assert not paged or eng.state.in_place
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=12) for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        outs[paged] = [r.out_tokens for r in reqs]
+    assert outs[True] == outs[False]
+    assert all(len(t) == 12 for t in outs[True])
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_decode_pages_read_counter(tiny_params, quant):
+    """kv_pages_read / kv_pages_capacity is the share of the lanes' pages
+    the decodes read: each live slot's pages up to its length in place,
+    the whole capacity (1.0) on the int8 gather path."""
+    ps, max_len, width = 8, 64, 2
+    eng = ServingEngine(TINY, tiny_params, max_batch=2, max_len=max_len, page_size=ps,
+                        kv_quant=quant)
+    req = Request(rid=0, prompt=np.arange(1, 12, dtype=np.int32), max_new_tokens=9)
+    eng.submit(req)
+    eng.run()
+    steps = eng.stats["decode_steps"]
+    assert steps == 8
+    stats = eng.pool.stats
+    assert stats["kv_pages_capacity"] == steps * width * (max_len // ps)
+    if quant:
+        assert stats["kv_pages_read"] == stats["kv_pages_capacity"]
+    else:
+        # the decode at length n reads the ceil(n / ps) pages holding it
+        assert stats["kv_pages_read"] == sum(-(-n // ps) for n in range(11, 11 + steps))
+        assert stats["kv_pages_read"] / stats["kv_pages_capacity"] == pytest.approx(18 / 128)
 
 
 # -- compile budget -----------------------------------------------------------
@@ -291,54 +533,80 @@ def test_timing_marks_are_monotone(tiny_params):
 # -- paged-attention kernel triplet -------------------------------------------
 
 
+def _pool_case(rng, bsz, hkv, hd, layers, pages, ps, npp, lens):
+    w = -(-hkv * hd // 128) * 128
+    kp = rng.normal(size=(layers, pages, ps, w)).astype(np.float32)
+    vp = rng.normal(size=(layers, pages, ps, w)).astype(np.float32)
+    tables = np.zeros((bsz, npp), np.int32)
+    perm = rng.permutation(np.arange(1, pages))
+    off = 0
+    for b in range(bsz):
+        n = -(-(int(lens[b]) + 1) // ps)
+        tables[b, :n] = perm[off : off + n]
+        off += n
+    return kp, vp, tables
+
+
 @pytest.mark.parametrize("group", [1, 4])
 def test_paged_decode_attention_matches_ref(group):
     from repro.kernels.flash_attention.ops import paged_decode_attention
     from repro.kernels.flash_attention.ref import paged_decode_attention_ref
 
     rng = np.random.default_rng(29)
-    bsz, hkv, hd, pages, ps, npp = 4, 2, 16, 11, 8, 4
+    bsz, hkv, hd, pages, ps, npp = 5, 2, 16, 24, 8, 4
     h = hkv * group
-    q = jnp.asarray(rng.normal(size=(bsz, 1, h, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(pages, ps, hkv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(pages, ps, hkv, hd)), jnp.float32)
-    tables = np.zeros((bsz, npp), np.int32)
-    perm = rng.permutation(np.arange(1, pages))
-    lens = np.asarray([5, 8, 17, 30], np.int32)
-    off = 0
-    for b in range(bsz):
-        n = -(-int(lens[b]) // ps)
-        tables[b, :n] = perm[off : off + n]
-        off += n
-    want = paged_decode_attention_ref(q, kp, vp, jnp.asarray(tables), jnp.asarray(lens))
-    got = paged_decode_attention(q, kp, vp, jnp.asarray(tables), jnp.asarray(lens))
+    # an empty lane, a page boundary, a full slot less its new token
+    lens = np.asarray([5, 0, 8, 17, 31], np.int32)
+    kp, vp, tables = _pool_case(rng, bsz, hkv, hd, 2, pages, ps, npp, lens)
+    q = jnp.asarray(rng.normal(size=(bsz, h, hd)), jnp.float32)
+    kn = jnp.asarray(rng.normal(size=(bsz, hkv, hd)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(bsz, hkv, hd)), jnp.float32)
+    args = (q, kn, vn, jnp.asarray(kp), jnp.asarray(vp), jnp.int32(1),
+            jnp.asarray(tables), jnp.asarray(lens))
+    want = paged_decode_attention_ref(*args)
+    got = paged_decode_attention(*args)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def test_paged_decode_attention_ignores_null_and_stale_pages():
-    """Garbage in the null page and in positions past `lengths` must not
-    leak into the output: poisoning them leaves the result unchanged."""
+    """Garbage in the null page, in positions past `lengths` and in other
+    layers must not leak into the output: poisoning them leaves the result
+    unchanged."""
     from repro.kernels.flash_attention.ops import paged_decode_attention
 
     rng = np.random.default_rng(31)
     bsz, h, hd, pages, ps, npp = 2, 2, 8, 6, 4, 3
-    q = jnp.asarray(rng.normal(size=(bsz, 1, h, hd)), jnp.float32)
-    kp = np.asarray(rng.normal(size=(pages, ps, h, hd)), np.float32)
-    vp = np.asarray(rng.normal(size=(pages, ps, h, hd)), np.float32)
+    q = jnp.asarray(rng.normal(size=(bsz, h, hd)), jnp.float32)
+    kn = jnp.asarray(rng.normal(size=(bsz, h, hd)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(bsz, h, hd)), jnp.float32)
+    w = 128
+    kp = np.asarray(rng.normal(size=(2, pages, ps, w)), np.float32)
+    vp = np.asarray(rng.normal(size=(2, pages, ps, w)), np.float32)
     tables = jnp.asarray([[1, 2, 0], [3, 0, 0]], jnp.int32)
     lens = jnp.asarray([6, 3], jnp.int32)
-    base = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp), tables, lens)
+
+    def run(k, v):
+        return paged_decode_attention(q, kn, vn, jnp.asarray(k), jnp.asarray(v),
+                                      jnp.int32(1), tables, lens)
+
+    base = run(kp, vp)
     kp2, vp2 = kp.copy(), vp.copy()
-    kp2[0], vp2[0] = 1e6, 1e6  # null page
-    kp2[2, 2:], vp2[2, 2:] = -1e6, -1e6  # positions 6,7 of slot 0 (past length)
-    kp2[3, 3:], vp2[3, 3:] = 1e6, -1e6  # position 3 of slot 1 (past length)
-    got = paged_decode_attention(q, jnp.asarray(kp2), jnp.asarray(vp2), tables, lens)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(got))
+    kp2[1, 0], vp2[1, 0] = 1e6, 1e6  # null page
+    kp2[1, 2, 2:], vp2[1, 2, 2:] = -1e6, -1e6  # positions 6,7 of slot 0 (past length)
+    kp2[1, 3, 3:], vp2[1, 3, 3:] = 1e6, -1e6  # position 3 of slot 1 (past length)
+    kp2[0], vp2[0] = 1e6, -1e6  # the other layer
+    kp2[1, 1, :, 2 * hd:], vp2[1, 1, :, 2 * hd:] = 1e6, 1e6  # row padding
+    np.testing.assert_array_equal(np.asarray(base), np.asarray(run(kp2, vp2)))
 
 
 def test_models_api_paged_cache_is_transformer_only():
+    from repro.kernels.flash_attention.kernel import pool_row_width
+
     pool = api.init_paged_cache(TINY, num_pages=4, page_size=8)
     for seg in pool:
+        assert seg["k"].shape == (TINY.n_layers, 4, 8, pool_row_width(TINY.kv_heads, TINY.hd))
+    assert pool_row_width(TINY.kv_heads, TINY.hd) == 128  # 2 kv heads of 8, padded to the lanes
+    for seg in api.init_paged_cache(TINY, num_pages=4, page_size=8, rows=False):
         assert seg["k"].shape == (TINY.n_layers, 4, 8, TINY.kv_heads, TINY.hd)
     rnn = ModelConfig(
         name="tiny-rglru",

@@ -142,7 +142,7 @@ def test_profiled_run_writes_nested_spans_with_their_args(params, tmp_path):
         "serve.admit": {"queued", "admitted"},
         "serve.prefill": {"rid", "tokens", "bucket", "resumed"},
         "serve.grow": {"preempted"},
-        "serve.decode": {"active", "ctx"},
+        "serve.decode": {"active", "ctx", "pages"},
         "serve.guard": set(),
         "serve.sample": {"n"},
     }
@@ -162,6 +162,8 @@ def test_profiled_run_writes_nested_spans_with_their_args(params, tmp_path):
     assert {int(a["admitted"]) for _, _, a in by["serve.admit"]} == {0, 1, 2}
     assert sum(int(a["n"]) for _, _, a in by["serve.sample"]) == sum(
         int(a["active"]) for _, _, a in by["serve.decode"])
+    assert sum(int(a["pages"]) for _, _, a in by["serve.decode"]) == \
+        eng.pool.stats["kv_pages_read"]
 
 
 def test_host_syncs_count_every_read_of_a_device_value(params):
@@ -234,10 +236,15 @@ def test_programs_have_stable_names(params):
     assert state_mod._decode_fn(TINY).__name__ == "decode"
     assert state_mod._prefill_fn(TINY, 64).__name__ == "prefill"
     pool = eng.pool
-    text = paged.paged_decode_fn(TINY).lower(
-        params, jnp.zeros((2, 1), jnp.int32), pool.segments, pool.tables, pool.index).as_text(debug_info=True)
+    args = (params, jnp.zeros((2, 1), jnp.int32), pool.segments, pool.tables, pool.index)
+    text = paged.paged_decode_fn(TINY).lower(*args).as_text(debug_info=True)
     assert "module @jit_paged_decode " in text
-    assert all(s in text for s in ("kv_gather", "kv_scatter", "kv_write"))
+    assert "kv_write" in text and "kv_gather" not in text
+    heads = api.init_paged_cache(TINY, pool.num_pages, pool.page_size, rows=False)
+    args = args[:2] + (heads,) + args[3:]
+    text = paged.gathered_decode_fn(TINY).lower(*args).as_text(debug_info=True)
+    assert "module @jit_paged_decode " in text
+    assert all(s in text for s in ("kv_gather", "kv_scatter"))
     text = paged.paged_prefill_fn(TINY, 16, 16).lower(
         params, np.zeros((1, 16), np.int32), 5, pool.segments, pool.table_row(0, 1)).as_text()
     assert "module @jit_paged_prefill " in text

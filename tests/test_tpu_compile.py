@@ -1,5 +1,7 @@
 """Compile the serving main path's Pallas kernels for a v5e chip at
-SmolLM-135M widths, with no chip attached.
+SmolLM-135M widths (the paged decode also at InternLM2-1.8B's and
+Qwen2.5-32B's, and its whole program at the chip benchmark cell's), with
+no chip attached.
 
 The TPU compiler refuses what interpret mode accepts: blocks off the
 (8, 128) tiling, unsupported primitives, more VMEM than a kernel may use.
@@ -108,16 +110,63 @@ def test_fused_rmsnorm_residual_compiles(one_chip, tokens):
 
 
 def test_paged_decode_compiles(one_chip):
-    max_len = 256
+    _compile_paged_decode(one_chip, H, HKV, HD)
+
+
+@pytest.mark.parametrize("heads", [(16, 8, 128), (40, 8, 128)],
+                         ids=["internlm2", "qwen2.5-32b"])
+def test_paged_decode_compiles_at_other_widths(one_chip, heads):
+    _compile_paged_decode(one_chip, *heads)
+
+
+def _compile_paged_decode(one_chip, h, hkv, hd):
+    max_len, layers = 256, 2
     pages_per_slot = max_len // PAGE_SIZE
     num_pages = 1 + DECODE_BATCH * pages_per_slot
+    w = -(-hkv * hd // 128) * 128
     text = _compiled_text(
         one_chip,
-        lambda q, k, v, t, n: paged_decode_attention_hp(q, k, v, t, n,
-                                                        interpret=False),
-        _bf16(DECODE_BATCH, H, HD),
-        _bf16(HKV, num_pages, PAGE_SIZE, HD),
-        _bf16(HKV, num_pages, PAGE_SIZE, HD),
+        lambda q, kn, vn, kp, vp, l, t, n: paged_decode_attention_hp(
+            q, kn, vn, kp, vp, l, t, n, scale=hd ** -0.5,
+            pages_per_block=128 // PAGE_SIZE, interpret=False),
+        _bf16(DECODE_BATCH, h, w),
+        _bf16(DECODE_BATCH, 1, w),
+        _bf16(DECODE_BATCH, 1, w),
+        _bf16(layers, num_pages, PAGE_SIZE, w),
+        _bf16(layers, num_pages, PAGE_SIZE, w),
+        ((), jnp.int32),
         ((DECODE_BATCH, pages_per_slot), jnp.int32),
         ((DECODE_BATCH,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_program_at_cell_widths(one_chip, monkeypatch):
+    """The whole paged decode program of the chip benchmark's cell
+    (InternLM2-1.8B: 24 layers, 16/8 heads of 128; 16 lanes of 2048
+    positions, page 16) runs the kernel, and its temporaries stay far
+    below the 3.2 GB pool: no dense sub-cache and no copy of the pool."""
+    from repro.kernels.flash_attention import ops
+    from repro.models import api
+    from repro.models.config import ModelConfig
+    from repro.serving import paged
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda interpret=None: False)
+    cfg = ModelConfig(name="internlm2-1.8b-cell", n_layers=24, d_model=2048,
+                      n_heads=16, kv_heads=8, head_dim=128, d_ff=8192,
+                      vocab=92544, tie_embeddings=False, rope_theta=1e6,
+                      norm_eps=1e-5, dtype="bfloat16", param_dtype="bfloat16")
+    lanes, max_len = 16, 2048
+    npp = max_len // PAGE_SIZE
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = jax.tree.map(sds, jax.eval_shape(
+        lambda: api.init_paged_cache(cfg, 1 + lanes * npp, PAGE_SIZE)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    compiled = paged.paged_decode_fn(cfg).lower(
+        params, i32(lanes, 1), pool, i32(lanes, npp), i32(lanes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
