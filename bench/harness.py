@@ -7,12 +7,44 @@ Everything that belongs to a cell is found by name:
 - `bench/cells/<cell>.json`: serving geometry (slots, `max_len`, page
   size) and the output check;
 - `bench/configs/<config>.json`: the program's arch id and overrides,
-  the sizes as run (checked against the program's config), the source;
+  the sizes as run (checked against the program's config), the source,
+  and the two yardsticks the configuration is judged by, each a Python
+  file under the benchmark's directory named by an optional key:
+  `"reference"` (default `reference.py`) and `"cost"` (default
+  `flops.py`).  A configuration of another architecture brings its own
+  two files and names them; a name that does not resolve is an error;
 - `bench/traffic/<mix>.json`: parameters of `bench.loadgen`;
 - `bench/metrics/<metric>.py`: `read(record) -> float | None`, one per
   metric, end-to-end and per-layer alike.  A metric `<q>.<cells>` that
   has no file of its own is read by `<q>.py`, so a quantity split by the
   end-to-end metric it moves keeps one reader.
+
+A reference module provides `served_gaps(model, w, prompt, served,
+pad_to, control=False) -> np.ndarray`: for one greedy request, how far
+the reference's logit of each served token lies below the reference's
+best; with `control`, the same gap of the token that its own forward in
+the precision just below the configuration's (fp8 e4m3 under bfloat16)
+puts first instead; and `lower_gaps(model, w, tokens)`, the program that
+`served_gaps` runs, lowered for weights and a padded token vector given
+as arrays or `ShapeDtypeStruct`s, so that `bench/rehearse.py` can
+compile it without a chip; `bench/reference.py`'s `gap_check(forward)`
+builds both from a reference's `forward`.  It also provides `READS`, the
+ends of the paths of every weight leaf it reads: a run whose weights
+hold a leaf it does not read (a bias, a router) is refused before the
+window (`check_reads`), not judged by a reference that leaves it out.
+It computes in float32 at `precision=highest`, takes the weights
+`bench/weights.py` makes from the seed and nothing else the program has
+made, imports nothing of the program, computes in blocks (of positions,
+layers or heads) where a whole-sequence pass would not fit beside the
+timed sizes, and says in its docstring where it departs from the
+published description of the architecture.
+
+A cost module provides `decode_cost(model, active, ctx_total)` and
+`prefill_cost(model, plen) -> (flops, bytes)`: the work a decode step of
+`active` requests over `ctx_total` cached positions, or a prefill of
+`plen` prompt tokens, needs by the algorithm, from the configuration's
+`model` sizes, not what the program happens to do.  The peaks and the
+least time they allow stay shared in `bench/flops.py`.
 
 The window drives the program's own entry points, `ServingEngine.submit`
 / `step`.  Each new output token is stamped on the host clock when the
@@ -27,7 +59,9 @@ engine's next line waits for anyway.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -36,10 +70,11 @@ import shutil
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 
 import numpy as np
 
-from bench import flops, loadgen, reference, trace_reduce, weights
+from bench import flops, loadgen, trace_reduce, weights
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -65,6 +100,8 @@ class Cell:
     per_layer: list[str]
     units: dict[str, str]
     bench_dir: Path
+    reference: ModuleType   # the configuration's plain reference
+    cost: ModuleType        # the configuration's cost model
 
 
 def _read_json(path: Path) -> dict:
@@ -92,7 +129,29 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 geometry=_read_json(bench_dir / "cells" / f"{name}.json"),
                 mix=_read_json(bench_dir / "traffic" / f"{w['traffic']}.json"),
                 e2e=mine(spec["end_to_end"]), per_layer=mine(spec["per_layer"]),
-                units=units, bench_dir=bench_dir)
+                units=units, bench_dir=bench_dir,
+                reference=yardstick(bench_dir, config.get("reference", "reference.py")),
+                cost=yardstick(bench_dir, config.get("cost", "flops.py")))
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: Path) -> ModuleType:
+    """The Python file at `path`, executed once per process, so that a
+    program it jits compiles once."""
+    mod_spec = importlib.util.spec_from_file_location("bench_" + path.stem.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def yardstick(bench_dir: Path, name: str) -> ModuleType:
+    """The module a configuration names as its reference or cost model: a
+    Python file under the benchmark's directory."""
+    base = Path(bench_dir).resolve()
+    path = (base / name).resolve()
+    if path.suffix != ".py" or base not in path.parents or not path.is_file():
+        raise HarnessError(f"no Python file {name!r} under {base}")
+    return _module(path)
 
 
 def metric_reader(bench_dir: Path, metric: str):
@@ -104,10 +163,7 @@ def metric_reader(bench_dir: Path, metric: str):
             break
     else:
         raise HarnessError(f"no reader for metric {metric!r} under {Path(bench_dir) / 'metrics'}")
-    mod_spec = importlib.util.spec_from_file_location("bench_metric_" + stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path.resolve()).read
 
 
 def model_config(config: dict):
@@ -278,6 +334,7 @@ class Record:
     drive clock are seconds; trace times are nanoseconds."""
 
     model: dict
+    cost: ModuleType    # the configuration's cost model
     tracks: list[Track]
     lo: float
     hi: float
@@ -320,6 +377,17 @@ class Record:
 
     def tokens_in_window(self) -> int:
         return sum(1 for tr in self.tracks for t in tr.times if self.lo <= t < self.hi)
+
+    def gaps_by_live(self) -> dict[int, tuple[int, float]]:
+        """How many token gaps end in a step that served k requests, and
+        their median (ms), by k: what a tail of the gaps is made of."""
+        served = collections.Counter(t for tr in self.tracks for t in tr.times)
+        by_k = collections.defaultdict(list)
+        for tr in self.tracks:
+            for a, b in zip(tr.times, tr.times[1:]):
+                if self.lo <= b <= self.hi:
+                    by_k[served[b]].append(b - a)
+        return {k: (len(v), float(np.median(v)) * 1e3) for k, v in sorted(by_k.items())}
 
     # trace-derived
     def busy(self) -> trace_reduce.Busy:
@@ -377,9 +445,18 @@ def pick_checked(tracks: list[Track], n: int, seed: int) -> list[Track]:
     return [longest] + [rest[int(i)] for i in rng.permutation(len(rest))[: max(n - 1, 0)]]
 
 
-def widest_gaps(model: dict, w, checked: list[Track], pad_to: int, control: bool = False) -> tuple[float, int]:
-    """The widest gap over every served token of the checked requests,
-    and how many tokens were checked."""
+def check_reads(reference: ModuleType, w) -> None:
+    """Refuse weights with a leaf that `reference` does not read."""
+    unread = [p for p in weights.leaf_paths(w)
+              if not any(p == r or p.endswith("/" + r) for r in reference.READS)]
+    if unread:
+        raise HarnessError(f"the reference {Path(reference.__file__).name} reads no {unread}")
+
+
+def widest_gaps(reference: ModuleType, model: dict, w, checked: list[Track], pad_to: int,
+                control: bool = False) -> tuple[float, int]:
+    """The widest gap over every served token of the checked requests by
+    the configuration's `reference`, and how many tokens were checked."""
     widest, n = 0.0, 0
     for tr in checked:
         g = reference.served_gaps(model, w, tr.req.prompt, tr.req.out_tokens, pad_to, control)
@@ -414,9 +491,10 @@ def _profile_options():
 def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         control: bool = False) -> dict:
     """Set up, warm up, ramp, measure, check.  Returns the result line.
-    With `control`, the fp8 control is also put in the program's place on
-    the same sample and judged by the same checks (`result["control"]`;
-    `bench/control.py`; the benchmark's own runs never do)."""
+    With `control`, the fp8 control of the configuration's reference is
+    also put in the program's place on the same sample and judged by the
+    same checks (`result["control"]`; `bench/control.py`; the benchmark's
+    own runs never do)."""
     import jax
     from tools.mozart_check.tracecheck import CompileMonitor
 
@@ -425,6 +503,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     model = cell.config["model"]
     device = jax.devices()[0]
     params = weights.make(cfg, seed, device)
+    check_reads(cell.reference, params)
     eng = make_engine(cfg, params, geo)
     warm_up(eng, geo, mix, cfg.vocab, np.random.default_rng([int(seed), 0xA11]))
     items = loadgen.generate(mix, seed, cfg.vocab, seconds, geo["max_len"])
@@ -458,14 +537,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     nan_steps = eng.stats["nan_steps"]
     for tr in source.tracks:
         tr.prefill_at = calls["prefill_at"].get(id(tr.req.prompt))
-    rec = Record(model=model, tracks=source.tracks, lo=lo, hi=hi, setup_s=opened["setup_s"],
-                 chip=device.id)
+    rec = Record(model=model, cost=cell.cost, tracks=source.tracks, lo=lo, hi=hi,
+                 setup_s=opened["setup_s"], chip=device.id)
     ctx = calls["decode_ctx"][opened["decodes"]:]
     capacity = geo["slots"] * geo["max_len"]
     info = {"window_s": hi - lo, "requests_done": sum(1 for tr in source.tracks if tr.req.done),
             "tokens_in_window": rec.tokens_in_window(),
             "kv_live_pct_mean": 100.0 * float(np.mean(ctx)) / capacity if ctx else None,
-            "kv_live_pct_max": 100.0 * max(ctx) / capacity if ctx else None}
+            "kv_live_pct_max": 100.0 * max(ctx) / capacity if ctx else None,
+            "gaps_by_live": {str(k): [n, round(ms, 3)] for k, (n, ms) in rec.gaps_by_live().items()},
+            "gaps_over_100ms": sum(1 for g in rec.token_gaps_s() if g > 0.1)}
     late = [tr.submit - tr.due for tr in rec.due_in_window() if not math.isnan(tr.submit)]
     if late:
         info["generator_late_p99_ms"] = float(np.percentile(late, 99) * 1e3)
@@ -491,7 +572,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     del eng, params, source
     gc.collect()
     w = weights.make(cfg, seed, device)
-    gap, n_tok = widest_gaps(model, w, checked, geo["max_len"])
+    gap, n_tok = widest_gaps(cell.reference, model, w, checked, geo["max_len"])
     checks = judge(chk, gap, n_tok, monitor.count, nan_steps)
     result = {
         "correct": all(c["ok"] for c in checks.values()),
@@ -504,7 +585,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     if breakdown is not None:
         result["breakdown"] = breakdown
     if control:
-        c_gap, c_tok = widest_gaps(model, w, checked, geo["max_len"], control=True)
+        c_gap, c_tok = widest_gaps(cell.reference, model, w, checked, geo["max_len"], control=True)
         c_checks = judge(chk, c_gap, c_tok, monitor.count, nan_steps)
         result["control"] = {"correct": all(c["ok"] for c in c_checks.values()), "checks": c_checks}
     del w

@@ -13,6 +13,13 @@ layers are stacked on a leading axis.
 `precision="fp8"` is the control: every matmul operand is rounded to
 fp8 e4m3 (per-tensor scaled, float32 accumulation), the step below the
 configuration's bfloat16 that a later change could be tempted to take.
+
+It is the default `reference` of a configuration (`bench/harness.py`
+says what a reference module provides).  It attends over the whole
+padded sequence at once: the scores of 16 heads over 2048 positions
+take 268 MB, which fits beside InternLM2-1.8B's weights; a configuration
+with many more heads or positions brings a reference that works in
+blocks.
 """
 
 from __future__ import annotations
@@ -26,6 +33,9 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 224.0  # reduce_precision's e4m3 (IEEE-style) saturates at 240
+# every weight leaf `forward` reads, each the end of a leaf's path
+READS = ("embed", "head", "final_norm/scale", "norm1/scale", "norm2/scale", "attn/wq", "attn/wk",
+         "attn/wv", "attn/wo", "mlp/w_gate", "mlp/w_in", "mlp/w_out")
 
 
 def _fp8(x):
@@ -95,35 +105,58 @@ def forward(model: dict, w, tokens, precision: str = "f32"):
     return _mm(x, head, precision)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def _gaps(model_items, w, tokens, targets, control: bool):
-    """Per position: how far the reference's logit of `targets` lies
-    below the reference's best.  With `control`, the target at each
-    position is what the fp8 forward puts first instead."""
-    model = dict(model_items)
-    ref = forward(model, w, tokens, "f32")
-    if control:
-        targets = jnp.argmax(forward(model, w, tokens, "fp8"), axis=-1)
-    picked = jnp.take_along_axis(ref, targets[:, None], axis=-1)[:, 0]
-    return jnp.max(ref, axis=-1) - picked
+def gap_check(forward):
+    """`(served_gaps, lower_gaps)`, the output check that `bench/harness.py`
+    asks of a reference module, for the reference whose logits are
+    `forward(model, w, tokens, precision)`: (S, V) in float32 for one
+    token sequence (S,), with every matmul operand in fp8 under
+    `precision="fp8"`.  A reference of another architecture writes its
+    own `forward` and takes these two from here."""
+
+    @functools.partial(jax.jit, static_argnums=(0, 4))
+    def gaps(model_items, w, tokens, targets, control: bool):
+        """Per position: how far the reference's logit of `targets` lies
+        below the reference's best.  With `control`, the target at each
+        position is what the fp8 forward puts first instead."""
+        model = dict(model_items)
+        ref = forward(model, w, tokens, "f32")
+        if control:
+            targets = jnp.argmax(forward(model, w, tokens, "fp8"), axis=-1)
+        picked = jnp.take_along_axis(ref, targets[:, None], axis=-1)[:, 0]
+        return jnp.max(ref, axis=-1) - picked
+
+    def served_gaps(model: dict, w, prompt, served, pad_to: int, control: bool = False) -> np.ndarray:
+        """Gaps of each served token of one request (greedy): the reference
+        runs once over prompt + served tokens, padded to `pad_to` so that one
+        executable serves every request (causal attention keeps the padding
+        out of every real position)."""
+        prompt = np.asarray(prompt, np.int32)
+        served = np.asarray(served, np.int32)
+        seq = np.concatenate([prompt, served[:-1]])
+        if len(seq) > pad_to:
+            raise ValueError(f"request of {len(seq)} tokens exceeds the reference length {pad_to}")
+        toks = np.zeros(pad_to, np.int32)
+        toks[: len(seq)] = seq
+        targets = np.zeros(pad_to, np.int32)
+        first = len(prompt) - 1
+        targets[first: first + len(served)] = served
+        with jax.default_matmul_precision("highest"):
+            g = gaps(_items(model), w, jnp.asarray(toks), jnp.asarray(targets), control)
+        return np.asarray(g)[first: first + len(served)]
+
+    def lower_gaps(model: dict, w, tokens):
+        """The program `served_gaps` runs, lowered for weights `w` and a
+        padded token vector `tokens` (arrays or `ShapeDtypeStruct`s), to
+        compile without a chip."""
+        with jax.default_matmul_precision("highest"):
+            return gaps.lower(_items(model), w, tokens, tokens, False)
+
+    return served_gaps, lower_gaps
 
 
-def served_gaps(model: dict, w, prompt, served, pad_to: int, control: bool = False) -> np.ndarray:
-    """Gaps of each served token of one request (greedy): the reference
-    runs once over prompt + served tokens, padded to `pad_to` so that one
-    executable serves every request (causal attention keeps the padding
-    out of every real position)."""
-    prompt = np.asarray(prompt, np.int32)
-    served = np.asarray(served, np.int32)
-    seq = np.concatenate([prompt, served[:-1]])
-    if len(seq) > pad_to:
-        raise ValueError(f"request of {len(seq)} tokens exceeds the reference length {pad_to}")
-    toks = np.zeros(pad_to, np.int32)
-    toks[: len(seq)] = seq
-    targets = np.zeros(pad_to, np.int32)
-    first = len(prompt) - 1
-    targets[first: first + len(served)] = served
-    items = tuple(sorted((k, v) for k, v in model.items() if not isinstance(v, (dict, list))))
-    with jax.default_matmul_precision("highest"):
-        g = _gaps(items, w, jnp.asarray(toks), jnp.asarray(targets), control)
-    return np.asarray(g)[first: first + len(served)]
+def _items(model: dict) -> tuple:
+    """The model's scalar sizes, hashable, as the gap program's static argument."""
+    return tuple(sorted((k, v) for k, v in model.items() if not isinstance(v, (dict, list))))
+
+
+served_gaps, lower_gaps = gap_check(forward)

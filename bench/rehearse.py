@@ -4,7 +4,8 @@
 
 Compiles, for one chip of a described `v5e:2x2` topology, the paged
 prefill of every bucket of the cell's engine, its decode at full width,
-and the reference the output check runs, and prints each program's
+and the reference that the cell's configuration names for the output
+check (its `lower_gaps`), and prints each program's
 `memory_analysis()` bytes.  It raises what the chip's compiler would
 raise.  Nothing runs, so it says nothing about times or results.
 """
@@ -26,7 +27,7 @@ def main(cell_name: str) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import harness, reference
+    from bench import harness
     from repro.models import api
     from repro.serving import paged
 
@@ -41,6 +42,7 @@ def main(cell_name: str) -> None:
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
 
     params = jax.tree.map(sds, jax.eval_shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
+    harness.check_reads(cell.reference, params)
     ps, slots, max_len = geo["page_size"], geo["slots"], geo["max_len"]
     npp = -(-max_len // ps)
     num_pages = 1 + slots * npp
@@ -61,11 +63,8 @@ def main(cell_name: str) -> None:
     report(f"decode width {slots}",
            dfn.lower(params, i32(slots, 1), segs, i32(slots, npp), i32(slots)).compile())
 
-    model = cell.config["model"]
-    items = tuple(sorted((k, v) for k, v in model.items() if not isinstance(v, (dict, list))))
-    with jax.default_matmul_precision("highest"):
-        report(f"reference length {max_len}",
-               reference._gaps.lower(items, params, i32(max_len), i32(max_len), False).compile())
+    report(f"reference length {max_len}",
+           cell.reference.lower_gaps(cell.config["model"], params, i32(max_len)).compile())
 
 
 if __name__ == "__main__":

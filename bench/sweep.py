@@ -59,7 +59,8 @@ def main() -> None:
         depth.clear()
         t0 = time.perf_counter()
         lo, hi = harness.drive(eng, src, lambda: time.perf_counter() - t0, args.seconds, lambda: None)
-        rec = harness.Record(model=cell.config["model"], tracks=src.tracks, lo=lo, hi=hi, setup_s=0.0)
+        rec = harness.Record(model=cell.config["model"], cost=cell.cost, tracks=src.tracks,
+                             lo=lo, hi=hi, setup_s=0.0)
         half = t0 + lo + (hi - lo) / 2
         first = [d for t, d in depth if t < half]
         second = [d for t, d in depth if t >= half]

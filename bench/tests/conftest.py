@@ -8,7 +8,8 @@ import sys
 from pathlib import Path
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-ROOT = Path(__file__).resolve().parents[2]
+TESTS = Path(__file__).resolve().parent
+ROOT = TESTS.parents[1]
 for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
@@ -26,11 +27,25 @@ def _write(path: Path, obj) -> None:
 
 
 def add_tiny_cells(root: Path, dtype: str = "bfloat16", gap_limit: float = 0.008) -> None:
-    """Define a tiny configuration and a cell by new files and entries
-    only.  The gap limit sits between the tiny bf16 program's widest gaps
-    on CPU (0 to 0.0018 over eight seeds) and its fp8 control's (0.020 to
-    0.095)."""
+    """Define two tiny configurations and a cell of each by new files and
+    entries only: `tiny.open`, a dense GQA decoder judged by the default
+    reference and cost model, and `tiny-qkv.open`, a decoder with QKV
+    bias (Qwen2.5's architecture) that brings its own two files, copied
+    from this directory.  The gap limit sits between the tiny bf16
+    program's widest gaps on CPU (0 to 0.0018 over eight seeds) and its
+    fp8 control's (0.020 to 0.095)."""
     bench = root / "bench"
+    for name in ("qkv_bias_reference.py", "qkv_bias_cost.py"):
+        shutil.copy(TESTS / name, bench / name)
+    _write(bench / "configs" / "tiny-qkv.json",
+           {"name": "tiny-qkv", "arch": "qwen2.5-32b", "source": "test",
+            "reference": "qkv_bias_reference.py", "cost": "qkv_bias_cost.py",
+            "overrides": dict(TINY_MODEL, dtype=dtype, param_dtype=dtype),
+            "model": dict(TINY_MODEL, swiglu=True, qkv_bias=True, tie_embeddings=False,
+                          rope_theta=1000000.0, dtype=dtype)})
+    _write(bench / "cells" / "tiny-qkv.open.json",
+           {"slots": 4, "max_len": 64, "page_size": 8,
+            "check": {"requests": 6, "min_tokens": 10, "max_logit_gap": gap_limit}})
     model = dict(TINY_MODEL, swiglu=True, tie_embeddings=True, rope_theta=10000.0, dtype=dtype)
     _write(bench / "configs" / "tiny.json",
            {"name": "tiny", "arch": "smollm-135m", "source": "test",
@@ -44,13 +59,14 @@ def add_tiny_cells(root: Path, dtype: str = "bfloat16", gap_limit: float = 0.008
     peaks["cpu"] = dict(peaks["TPU v5 lite"], source="test only: the CPU has no published peak here")
     _write(bench / "peaks.json", peaks)
     spec = json.loads((root / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "tiny", "source": "test", "file": "bench/configs/tiny.json",
-                            "reduced": ["n_layers"], "why": "test"})
-    spec["workloads"].append({"name": "tiny.open", "config": "tiny", "traffic": "tiny-open",
-                              "chips": 1, "why": "test"})
+    for config in ("tiny", "tiny-qkv"):
+        spec["configs"].append({"name": config, "source": "test", "reduced": ["n_layers"],
+                                "file": f"bench/configs/{config}.json", "why": "test"})
+        spec["workloads"].append({"name": f"{config}.open", "config": config,
+                                  "traffic": "tiny-open", "chips": 1, "why": "test"})
     for m in spec["per_layer"]:
         if "internlm2-1.8b.chat" in m["workloads"]:
-            m["workloads"].append("tiny.open")
+            m["workloads"] += ["tiny.open", "tiny-qkv.open"]
     _write(root / "BENCHMARK.json", spec)
 
 

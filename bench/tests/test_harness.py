@@ -53,6 +53,15 @@ def test_split_metric_is_read_by_its_quantitys_reader(tiny_root):
         harness.metric_reader(bench, "no_such_metric.rate")
 
 
+def test_gaps_by_live_counts_each_gap_by_its_steps_requests():
+    times = [[0.0, 1.0, 2.0, 3.0], [1.0, 2.0], [2.2, 2.4]]
+    tracks = [harness.Track(req=None, due=0.0, times=ts) for ts in times]
+    rec = harness.Record(model={}, cost=None, tracks=tracks, lo=0.5, hi=2.5, setup_s=0.0)
+    by_k = rec.gaps_by_live()
+    assert by_k == {1: (1, pytest.approx(200.0)), 2: (3, pytest.approx(1000.0))}
+    assert sum(n for n, _ in by_k.values()) == len(rec.token_gaps_s())
+
+
 @pytest.mark.parametrize("seed", [2**34 + 1, 3_000_000_011])
 def test_run_reports_the_cells_metrics_and_is_correct(tiny_root, seed):
     name = "tiny.open"

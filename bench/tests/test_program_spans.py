@@ -80,8 +80,9 @@ def tracks(with_admit=True):
 
 def record(trace, tracks):
     model = json.loads((BENCH / "configs" / "internlm2-1.8b.json").read_text())["model"]
-    return harness.Record(model=model, tracks=tracks, lo=0.0, hi=10.0, setup_s=1.0,
-                          peak=flops.peaks("TPU v5 lite"), trace=trace)
+    return harness.Record(model=model, cost=harness.yardstick(BENCH, "flops.py"), tracks=tracks,
+                          lo=0.0, hi=10.0, setup_s=1.0, peak=flops.peaks("TPU v5 lite"),
+                          trace=trace)
 
 
 def accepted_metrics():

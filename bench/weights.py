@@ -24,16 +24,31 @@ def seed_key(seed: int):
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32), impl="threefry2x32")
 
 
+VECTOR_STD = 0.1
+
+
 def _std(path: str, shape: tuple, n_layers: int) -> float:
+    """The spread of one leaf: layers are stacked on a leading axis of
+    every leaf under `segments`."""
     if path.endswith("scale"):
         return 0.1                      # norm weights spread around 1
     if path.startswith("embed") or path.startswith("head"):
         return 0.02
+    if len(shape) == 1 + path.startswith("segments"):
+        # a vector (per layer), such as a QKV bias or a router's selection
+        # bias: a tenth of the unit-scale activations it is added to
+        return VECTOR_STD
     fan_in = shape[-2]
     std = 1.0 / math.sqrt(fan_in)
     if path.endswith("wo") or path.endswith("w_out"):
         std /= math.sqrt(2 * n_layers)
     return std
+
+
+def leaf_paths(tree) -> list[str]:
+    """The path of each leaf of a parameter pytree, keys joined by `/`."""
+    return ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in p)
+            for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
 @functools.lru_cache(maxsize=4)
@@ -43,7 +58,7 @@ def _weights_fn(cfg, device):
 
     shapes = jax.eval_shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0)))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    paths = ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in p) for p, _ in leaves]
+    paths = leaf_paths(shapes)
 
     def build(key):
         keys = jax.random.split(key, len(leaves))
