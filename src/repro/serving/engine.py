@@ -79,20 +79,28 @@ nobody can use (`MOZART_DEADLINE_SHED=0` disables the feasibility
 check).  `queue_bound` (`MOZART_QUEUE_BOUND`) bounds the queue: a full
 queue sheds new submissions instead of growing without bound —
 backpressure the cluster router reads to route around hot replicas.
-Every decode's logits pass a cheap jitted all-finite guard
-(`MOZART_WATCHDOG_NAN`) BEFORE sampling: non-finite logits set
-`health["nan_detected"]` and the step emits nothing, so corrupted KV
-can never leak garbage tokens — the cluster watchdog quarantines the
-replica and the requeue path recovers its requests token-exactly.
+
+SAMPLING.  One jitted program (`sampling.sample_tokens`, profiled as
+`jit_sample_tokens`) samples every active lane of a decode step from
+the logits on the device, advances the engine's device-resident PRNG
+key once per active lane in the order the step serves them, and
+reduces the whole logits array to an all-finite flag; the host reads
+the tokens and the flag together, once a step.  Admission samples each first token with the
+same program at width 1.  With the NaN guard on (`MOZART_WATCHDOG_NAN`)
+non-finite logits set `health["nan_detected"]`, the step emits nothing
+and the key is not advanced, so corrupted KV can never leak garbage
+tokens — the cluster watchdog quarantines the replica and the requeue
+path recovers its requests token-exactly.
 
 SPANS AND COUNTERS.  With `serving.spans` on, `step()` writes
 `serve.step` and, nested inside it, `serve.admit`, one `serve.prefill`
-per admitted request, `serve.grow`, `serve.decode`, `serve.guard` and
-`serve.sample` into the profiler's trace.  `stats["host_syncs"]` counts
-every read of a device value by the host (each sampled token, each NaN
-guard); `stats["live_slot_steps"]` sums the live slots over decode
-steps, so mean occupancy is `live_slot_steps / (decode_steps *
-max_batch)`.
+per admitted request, `serve.grow`, `serve.decode` and `serve.sample`
+(the sampling program and its one read) into the profiler's trace.
+`stats["host_syncs"]` counts every read of a device value by the host:
+one per admitted request and one per decode step, guard or no guard
+(spec-decode reads its guard and its two token arrays);
+`stats["live_slot_steps"]` sums the live slots over decode steps, so
+mean occupancy is `live_slot_steps / (decode_steps * max_batch)`.
 """
 from __future__ import annotations
 
@@ -107,10 +115,9 @@ import numpy as np
 from repro.launch import knobs
 from repro.models.config import ModelConfig
 from . import paged as paged_kv
-from . import resilience
 from . import spans
 from . import state as state_mod
-from .sampling import sample
+from .sampling import sample_tokens
 
 # re-exports: tests and downstream modules address these through the
 # engine module (and monkeypatch _rewind_inactive by this name)
@@ -146,6 +153,16 @@ class Request:
     t_done: float | None = None
     admit_seq: int = -1           # engine admission order (preemption picks max)
     requeues: int = 0             # failovers survived (cluster retry budget)
+
+
+def sample(logits, key, *, lanes, temperature, n, out: dict):
+    """Draw the tokens of `lanes` in one program (`sampling.sample_tokens`)
+    and return them on the device; the all-finite guard and the advanced
+    key go into `out`.  Every token the engine emits is drawn here, so a
+    fault injected under this name reaches every request's stream."""
+    tokens, out["finite"], out["key"] = sample_tokens(
+        logits, lanes, temperature, n, key)
+    return tokens
 
 
 def _rewind_hook(index, inactive):
@@ -410,10 +427,9 @@ class ServingEngine:
                     if resumed:
                         self.next_token[b, 0] = req.out_tokens[-1]
                         continue
-                    self.key, k = jax.random.split(self.key)
-                    tok = int(sample(last[0, -1:], k,
-                                     temperature=req.temperature)[0])
-                    self.stats["host_syncs"] += 1
+                    toks, _, self.key = self._sample(
+                        last, [0], [req.temperature])
+                    tok = int(toks[0])
                     req.out_tokens.append(tok)
                     if req.t_first is None:
                         req.t_first = time.monotonic()
@@ -491,7 +507,7 @@ class ServingEngine:
         return sum(self._slot_pos(b) + 1 for b in active)
 
     def _advance(self, active: list[int]) -> bool:
-        """Decode the active slots one step, guard, sample, finish.
+        """Decode the active slots one step, sample and guard, finish.
         Returns False when the NaN guard swallowed the step.  Subclasses
         (spec-decode) replace this with multi-token propose/verify."""
         fn = self._paged_decode if self.paged else self._decode
@@ -502,33 +518,48 @@ class ServingEngine:
                         **pages):
             logits, lane = self.state.decode(fn, self.params,
                                              self.next_token, active)
-        if self.guard_nan:
-            with spans.span("guard"):
-                finite = resilience.logits_finite(logits)
-            self.stats["host_syncs"] += 1
-            if not finite:
-                # corrupted KV / sick kernel: emit NOTHING from non-finite
-                # logits (garbage tokens would poison the requests'
-                # streams beyond token-exact recovery); flag for the
-                # watchdog
-                self.health["nan_detected"] = True
-                self.stats["nan_steps"] += 1
-                return False
         with spans.span("sample", n=len(active)):
-            for b in active:
-                req = self.slots[b]
-                self.key, k = jax.random.split(self.key)
-                tok = int(sample(logits[lane[b], -1:], k,
-                                 temperature=req.temperature)[0])
-                self.stats["host_syncs"] += 1
-                req.out_tokens.append(tok)
-                self.next_token[b, 0] = tok
-                self.stats["tokens_out"] += 1
-                if len(req.out_tokens) >= req.max_new_tokens or \
-                        tok == self.eos_id:
-                    self._finish(b, "eos" if tok == self.eos_id
-                                 else "max_new_tokens")
+            toks, finite, key = self._sample(
+                logits, [lane[b] for b in active],
+                [self.slots[b].temperature for b in active])
+        if self.guard_nan and not finite:
+            # corrupted KV / sick kernel: emit NOTHING from non-finite
+            # logits (garbage tokens would poison the requests' streams
+            # beyond token-exact recovery), keep the key as it was, and
+            # flag for the watchdog
+            self.health["nan_detected"] = True
+            self.stats["nan_steps"] += 1
+            return False
+        self.key = key
+        for b, tok in zip(active, toks.tolist()):
+            req = self.slots[b]
+            req.out_tokens.append(tok)
+            self.next_token[b, 0] = tok
+            self.stats["tokens_out"] += 1
+            if len(req.out_tokens) >= req.max_new_tokens or \
+                    tok == self.eos_id:
+                self._finish(b, "eos" if tok == self.eos_id
+                             else "max_new_tokens")
         return True
+
+    def _sample(self, logits, lanes: list[int], temps: list[float]):
+        """Sample a token for each `lanes[i]` row of `logits` at
+        temperature `temps[i]` in one program, and read the tokens and the
+        all-finite guard in one host read.  Returns `(tokens, finite,
+        key)`: the (len(lanes),) tokens, the guard and the advanced key,
+        which the caller keeps or drops.  Lane and temperature arrays are
+        padded to the logits' width, so the live count never recompiles."""
+        w, n = logits.shape[0], len(lanes)
+        lane_arr = np.zeros((w,), np.int32)
+        lane_arr[:n] = lanes
+        temp_arr = np.zeros((w,), np.float32)
+        temp_arr[:n] = temps
+        drawn: dict = {}
+        toks = sample(logits, self.key, lanes=lane_arr, temperature=temp_arr,
+                      n=np.int32(n), out=drawn)
+        toks, finite = jax.device_get((toks, drawn["finite"]))
+        self.stats["host_syncs"] += 1
+        return toks[:n], bool(finite), drawn["key"]
 
     def _grow_pages(self, live: list[int]) -> list[int]:
         """Make every live slot's next KV write backed by a page,

@@ -10,7 +10,8 @@ can trust under churn.  This module holds the pieces
 `serving.cluster.ServingCluster` threads through its step loop:
 
 * **NaN/Inf guard** — `logits_finite` is a cheap jitted all-finite
-  reduction the engine runs on every decode's logits BEFORE sampling, so
+  reduction over a decode's logits.  The engine computes the same
+  reduction inside its sampling program and reads it with the tokens, so
   a corrupted KV page (HBM bit flip, bad kernel) can never leak garbage
   tokens into a request's stream: the engine raises its
   ``health["nan_detected"]`` flag and emits nothing, and the cluster

@@ -272,3 +272,102 @@ def test_compacted_decode_matches_full_batch(temperature):
     assert comp == full
     assert emul == full
     assert steps_comp == steps_emul > steps_full
+
+
+# -- batched sampling ----------------------------------------------------------
+
+
+def _loop_sample(logits, key, lanes, temps):
+    """The per-slot loop the batched sampler replaces: split the key once
+    per active slot, in order, and sample that slot's row."""
+    toks = []
+    for lane, t in zip(lanes, temps):
+        key, k = jax.random.split(key)
+        toks.append(int(sample(logits[lane, -1:], k, temperature=t)[0]))
+    return toks, key
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "width, lanes, temps",
+    [
+        pytest.param(4, [0, 1, 2, 3], [0.0] * 4, id="greedy"),
+        pytest.param(4, [2, 0, 3, 1], [0.0, 0.7, 0.0, 3.0], id="mixed"),
+        pytest.param(4, [0, 1, 2, 3], [0.5, 1.0, 2.0, 3.0], id="sampled"),
+        pytest.param(4, [1, 0], [0.7, 1.3], id="padding"),
+        # the dense state's full-width lane map {3: 3, 1: 1}: not contiguous
+        pytest.param(4, [3, 1], [1.5, 0.0], id="dense-full-width"),
+        pytest.param(1, [0], [3.0], id="admission"),
+    ],
+)
+def test_batched_sampler_matches_the_per_slot_loop(width, lanes, temps, dtype):
+    """One program, one read: the same tokens, and the same key left
+    behind, as splitting the key and sampling slot by slot."""
+    eng = _stub_engine(max_batch=4)
+    eng.key = jax.random.PRNGKey(2**31 - 5)
+    rng = np.random.default_rng(width * 10 + len(lanes))
+    logits = jnp.asarray(rng.normal(size=(width, 1, TINY.vocab)) * 2, dtype)
+    want, want_key = _loop_sample(logits, eng.key, lanes, temps)
+    toks, finite, key = eng._sample(logits, lanes, temps)
+    assert toks.tolist() == want and finite
+    np.testing.assert_array_equal(np.asarray(key), np.asarray(want_key))
+    assert eng.stats["host_syncs"] == 1
+
+
+def test_active_count_never_recompiles_the_sampler():
+    """CompileMonitor-verified: after a warm-up at full width, a paged
+    engine whose active count runs through every value from 1 to its
+    width, with greedy and sampled requests, compiles nothing."""
+    from tools.mozart_check.tracecheck import CompileMonitor
+
+    params = api.init_params(TINY, jax.random.PRNGKey(0))
+    eng = ServingEngine(TINY, params, max_batch=4, max_len=32, paged=True)
+    prompt = (np.arange(6) % TINY.vocab).astype(np.int32)
+    for i in range(4):
+        eng.submit(Request(rid=i, prompt=prompt, max_new_tokens=3))
+    eng.run()
+    served = []
+    with CompileMonitor() as mon:
+        for i in range(4):
+            eng.submit(Request(rid=10 + i, prompt=prompt, max_new_tokens=6 - i,
+                               temperature=0.8 * (i % 2)))
+            served.append(eng.step())
+        while eng.queue or any(s is not None for s in eng.slots):
+            served.append(eng.step())
+    assert set(served) >= {1, 2, 3, 4}
+    assert mon.count == 0, mon.events
+
+
+@pytest.mark.parametrize("guard", [True, False])
+def test_non_finite_logits_emit_nothing_and_keep_the_key(guard):
+    """With the guard on, a step over non-finite logits emits no token,
+    flags the engine and leaves its key where it was; with the guard off
+    the flag is not read and the step samples as usual."""
+    eng = _stub_engine(max_batch=2)
+    eng.guard_nan = guard
+    reqs = [Request(rid=i, prompt=np.asarray([3 + i], np.int32), max_new_tokens=8)
+            for i in range(2)]
+    for r in reqs:
+        eng.submit(r)
+    assert eng.step() == 2
+    real = eng._decode
+
+    def poisoned(params, tokens, cache):
+        logits, cache = real(params, tokens, cache)
+        return logits.at[0, 0, 0].set(jnp.nan), cache
+
+    eng._decode = poisoned
+    key, emitted = np.asarray(eng.key), [len(r.out_tokens) for r in reqs]
+    syncs = eng.stats["host_syncs"]
+    served = eng.step()
+    assert eng.stats["host_syncs"] == syncs + 1
+    if guard:
+        assert served == 0
+        assert [len(r.out_tokens) for r in reqs] == emitted
+        assert eng.health["nan_detected"] and eng.stats["nan_steps"] == 1
+        np.testing.assert_array_equal(np.asarray(eng.key), key)
+    else:
+        assert served == 2
+        assert [len(r.out_tokens) for r in reqs] == [n + 1 for n in emitted]
+        assert not eng.health["nan_detected"] and eng.stats["nan_steps"] == 0
+        assert not np.array_equal(np.asarray(eng.key), key)

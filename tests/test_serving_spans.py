@@ -18,7 +18,7 @@ import pytest
 
 from repro.models import api
 from repro.models.config import ModelConfig
-from repro.serving import paged, resilience, spans
+from repro.serving import paged, resilience, sampling, spans
 from repro.serving import state as state_mod
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.specdec import SpecDecodeEngine, shared_trunk_draft
@@ -143,7 +143,6 @@ def test_profiled_run_writes_nested_spans_with_their_args(params, tmp_path):
         "serve.prefill": {"rid", "tokens", "bucket", "resumed"},
         "serve.grow": {"preempted"},
         "serve.decode": {"active", "ctx", "pages"},
-        "serve.guard": set(),
         "serve.sample": {"n"},
     }
     assert set(by) == set(want)
@@ -168,26 +167,24 @@ def test_profiled_run_writes_nested_spans_with_their_args(params, tmp_path):
 
 def test_host_syncs_count_every_read_of_a_device_value(params):
     """Two requests fill both slots: two first-token samples, then two
-    steps of two sampled tokens and one guard each; a third request is
-    admitted once they finish (one sample) and decodes one step alone."""
-    eng = _engine(params)
-    for i, n in enumerate((3, 3, 2)):
-        eng.submit(Request(rid=i, prompt=_prompt(6, i), max_new_tokens=n))
-    eng.step()
-    assert eng.stats["host_syncs"] == 2 + (2 + 1)
-    eng.step()
-    assert eng.stats["host_syncs"] == 5 + (2 + 1)
-    eng.step()
-    assert eng.stats["host_syncs"] == 8 + 1 + (1 + 1)
-    assert eng.stats["decode_steps"] == 3 and not eng.queue
-    assert eng.stats["live_slot_steps"] == 2 + 2 + 1
-    # without the guard, one fewer per decode step
-    eng = _engine(params)
-    eng.guard_nan = False
-    for i, n in enumerate((3, 3, 2)):
-        eng.submit(Request(rid=i, prompt=_prompt(6, i), max_new_tokens=n))
-    eng.run()
-    assert eng.stats["host_syncs"] == 3 + 2 + 2 + 1
+    steps that each read their two tokens and the guard at once; a third
+    request is admitted once they finish (one sample) and decodes one
+    step alone.  One read per admission and one per decode step, with the
+    guard on or off."""
+    for guard in (True, False):
+        eng = _engine(params)
+        eng.guard_nan = guard
+        for i, n in enumerate((3, 3, 2)):
+            eng.submit(Request(rid=i, prompt=_prompt(6, i), max_new_tokens=n))
+        eng.step()
+        assert eng.stats["host_syncs"] == 2 + 1
+        eng.step()
+        assert eng.stats["host_syncs"] == 3 + 1
+        eng.step()
+        assert eng.stats["host_syncs"] == 4 + 1 + 1
+        assert eng.stats["decode_steps"] == 3 and not eng.queue
+        assert eng.stats["live_slot_steps"] == 2 + 2 + 1
+        assert eng.stats["host_syncs"] == eng.stats["prefills"] + eng.stats["decode_steps"]
 
 
 def test_spec_decode_counts_its_guard_and_two_reads_per_step(params):
@@ -250,6 +247,10 @@ def test_programs_have_stable_names(params):
     assert "module @jit_paged_prefill " in text
     text = resilience._ALL_FINITE.lower(jnp.ones((2, 3))).as_text()
     assert "module @jit_logits_finite " in text
+    text = sampling.sample_tokens.lower(
+        jnp.ones((2, 1, 3)), np.zeros(2, np.int32), np.zeros(2, np.float32), np.int32(1),
+        jax.random.PRNGKey(0)).as_text()
+    assert "module @jit_sample_tokens " in text
 
 
 def test_serve_profile_writes_the_engine_spans(tmp_path):
